@@ -13,15 +13,15 @@ os.environ.setdefault("XLA_FLAGS",
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.models.moe import MoESpec, apply_moe, apply_moe_ep, moe_defs
 from repro.models.params import init_params
 
 
 def main() -> None:
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     spec = MoESpec(d_model=64, n_experts=8, top_k=2, d_ff=128,
                    capacity_factor=2.0, ep_axis="model")
     params = init_params(moe_defs(spec), jax.random.PRNGKey(0))
@@ -33,9 +33,9 @@ def main() -> None:
 
     w_specs = {k: (P() if k.startswith(("router", "shared"))
                    else P("model", None, None)) for k in params}
-    ep = jax.jit(shard_map(
+    ep = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(w_specs, P("data", "model", None)),
-        out_specs=(P("data", "model", None), P()), check_rep=False))
+        out_specs=(P("data", "model", None), P()), check_vma=False))
 
     out, aux = ep(params, x)
     ref, _ = apply_moe(params, x, spec)

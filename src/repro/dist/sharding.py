@@ -73,8 +73,9 @@ def abstract_mesh(shape: dict[str, int]):
     """Device-free mesh stand-in from an ``{axis: size}`` dict — lets
     tests/benchmarks derive specs for 256/512-chip production meshes on
     a laptop."""
-    from jax.sharding import AbstractMesh
-    return AbstractMesh(tuple(shape.items()))
+    from jax.sharding import AbstractMesh, AxisType
+    return AbstractMesh(tuple(shape.values()), tuple(shape),
+                        (AxisType.Auto,) * len(shape))
 
 
 def data_axes(mesh) -> tuple[str, ...]:

@@ -18,6 +18,7 @@ from repro.kernels.ops import (
     matmul,
     observe,
     resolve_backend,
+    resolve_interpret,
     supported_routine,
     syrk,
     trsm,
@@ -35,7 +36,7 @@ __all__ = [
     "matmul_pallas", "grouped_matmul_pallas", "flash_attention_pallas",
     "matmul", "syrk", "trsm", "grouped_matmul", "flash_attention",
     "dispatch_hint", "grouped_dispatch_hint", "observe",
-    "resolve_backend", "supported_routine",
+    "resolve_backend", "resolve_interpret", "supported_routine",
     "DispatchEvent", "DispatchRecorder",
     "matmul_ref", "syrk_ref", "trsm_ref", "grouped_matmul_ref",
     "flash_attention_ref",

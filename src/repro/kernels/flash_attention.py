@@ -40,10 +40,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 __all__ = ["flash_attention_pallas", "flash_tile_map", "flash_grid_counts"]
 
 _NEG_INF = -1e30
@@ -303,7 +299,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                               sm_scale=sm_scale),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((bh, gq * bq_, d), q.dtype),
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
         )(jnp.asarray(qt), jnp.asarray(kvt), jnp.asarray(first),
@@ -323,7 +319,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=pl.BlockSpec((1, bq_, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, gq * bq_, d), q.dtype),
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, kp, vp)

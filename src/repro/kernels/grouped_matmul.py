@@ -18,10 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 __all__ = ["grouped_matmul_pallas"]
 
 
@@ -74,7 +70,7 @@ def grouped_matmul_pallas(x: jax.Array, w: jax.Array, *, bm: int = 128,
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, kk: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, gm * bm, gn * bn), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -3,8 +3,9 @@
 ``matmul`` / ``grouped_matmul`` / ``flash_attention`` are the entry
 points the model layers call.  Backend selection:
 
-  * ``pallas``  — the Pallas TPU kernels (interpret=True off-TPU, used by
-    the correctness tests);
+  * ``pallas``  — the Pallas TPU kernels, compiled by Mosaic on a TPU
+    and run by the Pallas interpreter when JAX's platform is the CPU
+    (see :func:`resolve_interpret`; the correctness tests run there);
   * ``xla``     — jnp reference implementations.  The default on CPU
     hosts and inside the multi-pod dry-run, where XLA's SPMD partitioner
     handles the sharded einsums and Mosaic kernels cannot lower.
@@ -49,7 +50,7 @@ from repro.kernels.matmul import matmul_pallas
 
 __all__ = ["matmul", "syrk", "trsm", "grouped_matmul", "flash_attention",
            "dispatch_hint", "grouped_dispatch_hint", "observe",
-           "resolve_backend", "supported_routine"]
+           "resolve_backend", "resolve_interpret", "supported_routine"]
 
 Backend = Literal["auto", "pallas", "xla"]
 
@@ -68,9 +69,22 @@ def resolve_backend(backend: Backend = "auto") -> str:
             raise ValueError(
                 f"ADSALA_BACKEND={env!r}; expected 'pallas' or 'xla'")
         return env
-    if os.environ.get("ADSALA_FORCE_PALLAS"):
-        return "pallas"
     return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """Whether a Pallas kernel runs in the Pallas interpreter.
+
+    An explicit ``interpret`` wins.  Left as ``None``, interpret mode is
+    on only when JAX's platform is the CPU, where Mosaic cannot compile
+    and the interpreter stands in for the chip.  On a TPU it is off, so
+    the kernels always compile there; on any other platform it is off
+    too, and the kernel fails to lower instead of silently running the
+    interpreter.
+    """
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() == "cpu"
 
 
 def supported_routine(routine: str, tuner: AdsalaTuner | None) -> str:
@@ -204,9 +218,8 @@ def matmul(a: jax.Array, b: jax.Array, *,
         return ref.matmul_ref(a, b)
     bm, bk, bn = (tile if tile is not None
                   else cfg.tile if cfg is not None else DEFAULT_TILES[3])
-    interp = (jax.default_backend() != "tpu") if interpret is None \
-        else interpret
-    return matmul_pallas(a, b, bm=bm, bk=bk, bn=bn, interpret=interp)
+    return matmul_pallas(a, b, bm=bm, bk=bk, bn=bn,
+                         interpret=resolve_interpret(interpret))
 
 
 def syrk(a: jax.Array, b: jax.Array | None = None, *,
@@ -248,10 +261,9 @@ def syrk(a: jax.Array, b: jax.Array | None = None, *,
         return ref.syrk_ref(a, b, lower=lower)
     bm, bk, bn = (tile if tile is not None
                   else cfg.tile if cfg is not None else DEFAULT_TILES[3])
-    interp = (jax.default_backend() != "tpu") if interpret is None \
-        else interpret
     c = matmul_pallas(a, (a if b is None else b).T, bm=bm, bk=bk, bn=bn,
-                      interpret=interp, out_dtype=jnp.float32)
+                      interpret=resolve_interpret(interpret),
+                      out_dtype=jnp.float32)
     c = jnp.tril(c) if lower else jnp.triu(c)
     return c.astype(a.dtype)
 
@@ -289,8 +301,7 @@ def trsm(a: jax.Array, b: jax.Array, *,
         return ref.trsm_ref(a, b, lower=lower, unit_diag=unit_diag)
     bm, bk, bn = (tile if tile is not None
                   else cfg.tile if cfg is not None else DEFAULT_TILES[3])
-    interp = (jax.default_backend() != "tpu") if interpret is None \
-        else interpret
+    interp = resolve_interpret(interpret)
     a32 = a.astype(jnp.float32)
     b32 = b.astype(jnp.float32)
     starts = list(range(0, m, bm))
@@ -378,9 +389,8 @@ def grouped_matmul(x: jax.Array, w: jax.Array, *,
         bm, bk, bn = cfgs[big].tile
     else:
         bm, bk, bn = DEFAULT_TILES[3]  # (256, 256, 256)
-    interp = (jax.default_backend() != "tpu") if interpret is None \
-        else interpret
-    return grouped_matmul_pallas(x, w, bm=bm, bk=bk, bn=bn, interpret=interp)
+    return grouped_matmul_pallas(x, w, bm=bm, bk=bk, bn=bn,
+                                 interpret=resolve_interpret(interpret))
 
 
 #: untuned-XLA fallback: the longest causal self-attention whose scores
@@ -538,8 +548,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                        chunk=min(512, max(1, sq)))
     recorder.record(rt, sq, d, skv, config=cfg, cache_hit=hit,
                     site=site, count=count)
-    interp = (jax.default_backend() != "tpu") if interpret is None \
-        else interpret
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                   sm_scale=sm_scale, bq=bq, bkv=bkv,
-                                  interpret=interp, grid=grid)
+                                  interpret=resolve_interpret(interpret),
+                                  grid=grid)

@@ -1,12 +1,10 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape) on the
 production meshes and record memory/cost/collective analyses.
 
 MUST be executed as its own process (``python -m repro.launch.dryrun``):
-the XLA_FLAGS line above creates 512 placeholder host devices and must
-run before any other jax import in the process.
+the environment lines below pin the CPU platform (a CPU-only program
+must not claim a TPU it finds) and create 512 placeholder host devices,
+and must run before any other jax import in the process.
 
 Per cell this emits results/dryrun/<arch>_<shape>_<mesh>.json with:
   memory_analysis  — bytes per device (arguments / temp / output / peak)
@@ -14,6 +12,11 @@ Per cell this emits results/dryrun/<arch>_<shape>_<mesh>.json with:
   collectives      — per-op-kind byte totals parsed from post-SPMD HLO
   model_flops      — 6·N·D (dense) / 6·N_active·D (MoE) for §Roofline
 """
+
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse
 import json

@@ -1,13 +1,21 @@
-"""Production mesh construction.
+"""Mesh construction: the one place this repo builds a device mesh.
 
-A FUNCTION (not a module-level constant) so importing this module never
+FUNCTIONS (not module-level constants) so importing this module never
 touches jax device state — required by the dry-run, whose XLA_FLAGS must
 be set before the first jax initialisation.
+
+Every axis is ``AxisType.Auto``: the models are written for GSPMD
+propagation — shardings enter at the jit boundary (and through
+``shard_map`` for the MoE paths) and XLA derives the rest.
+``jax.make_mesh`` defaults to ``AxisType.Explicit`` since JAX 0.9, under
+which every gather and einsum on a sharded operand would need its own
+``out_sharding`` (the embedding lookup is the first to refuse).
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh", "dp_axes", "tp_axis"]
 
@@ -26,11 +34,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     else:
         shape = (256 // tp, tp)
         axes = ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, build_model, get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import Ctx
 from repro.train.step import make_ctx
 
@@ -28,7 +29,12 @@ from repro.train.step import make_ctx
 DRIFT_WARN = 0.25
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> dict | None:
+    """Parse ``argv`` (default ``sys.argv[1:]``) and serve.
+
+    ``--queue`` returns the continuous-batching run's summary (see
+    :func:`_serve_queue`); the fixed-batch loop returns None.
+    """
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-1.6b", choices=ARCH_IDS)
     ap.add_argument("--scale", default="smoke", choices=["smoke", "full"])
@@ -86,7 +92,8 @@ def main() -> None:
                          "re-install; keeps the online install cheap")
     ap.add_argument("--reinstall-cooldown", type=float, default=300.0,
                     help="minimum seconds between re-installs")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_config if args.scale == "full"
            else get_smoke_config)(args.arch)
@@ -174,8 +181,7 @@ def main() -> None:
                          "artifact")
 
     if args.queue:
-        _serve_queue(args, cfg, model, params, tuner, manager, recs)
-        return
+        return _serve_queue(args, cfg, model, params, tuner, manager, recs)
 
     cache_len = args.prompt_len + args.gen_tokens
     pctx = make_ctx(None, "prefill", cache_len=cache_len, remat=False,
@@ -293,9 +299,13 @@ def _report_tail(args, cfg, recs, tuner, manager) -> None:
         print(f"[serve] workload profile written to {args.profile_out}")
 
 
-def _serve_queue(args, cfg, model, params, tuner, manager, recs) -> None:
+def _serve_queue(args, cfg, model, params, tuner, manager, recs) -> dict:
     """Trace-driven continuous batching: ragged requests through the
-    paged-KV scheduler, re-install drift checks riding the step hook."""
+    paged-KV scheduler, re-install drift checks riding the step hook.
+
+    Returns ``{"finished": {rid: FinishedSeq}, "tokens", "wall_s",
+    "tok_s"}``; the wall time includes every compile inside the run.
+    """
     import numpy as np
 
     from repro.serve.kv_cache import pages_for
@@ -340,6 +350,8 @@ def _serve_queue(args, cfg, model, params, tuner, manager, recs) -> None:
           f"{list(finished[sample].tokens)[:8]}")
     sched.alloc.check()
     _report_tail(args, cfg, recs, tuner, manager)
+    return {"finished": finished, "tokens": toks, "wall_s": wall,
+            "tok_s": tps}
 
 
 if __name__ == "__main__":

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.configs import ARCH_IDS, build_model, get_config, get_smoke_config
 from repro.data.pipeline import Prefetcher, SyntheticLM, make_global_batch
 from repro.ft.driver import DriverConfig, TrainDriver
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models.config import SHAPES, ShapeSpec
 from repro.train.optim import AdamWConfig
@@ -43,6 +44,7 @@ def main() -> None:
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.scale == "full":
         cfg = get_config(args.arch)

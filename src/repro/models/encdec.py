@@ -99,14 +99,14 @@ class EncDecLM:
 
     # -- encoder -----------------------------------------------------------
     def encode(self, params: dict, audio_emb: jax.Array,
-               tuner=None) -> jax.Array:
+               tuner=None, backend: str = "auto") -> jax.Array:
         cfg = self.cfg
         x = audio_emb + params["pos_enc"][None, : audio_emb.shape[1]]
         spec = _attn_spec(cfg, causal=False)
         for p in params["encoder"]:
             h, _ = L.attention_train(
                 p["attn"], L.apply_norm(p["ln1"], x, cfg.norm_kind), spec,
-                tuner=tuner)
+                tuner=tuner, backend=backend)
             x = x + h
             x = x + L.apply_mlp(
                 p["mlp"], L.apply_norm(p["ln2"], x, cfg.norm_kind),
@@ -126,7 +126,7 @@ class EncDecLM:
         for p in params["decoder"]:
             h, kv = L.attention_train(
                 p["self_attn"], L.apply_norm(p["ln1"], x, cfg.norm_kind),
-                sa, tuner=ctx.tuner)
+                sa, tuner=ctx.tuner, backend=ctx.backend)
             x = x + h
             ek, ev = _project_enc_kv(p["cross_attn"], enc, ca)
             x = x + _cross_attention(
@@ -146,13 +146,15 @@ class EncDecLM:
     def loss(self, params: dict, batch: dict, ctx: Ctx | None = None
              ) -> jax.Array:
         ctx = ctx or Ctx(mode="train")
-        enc = self.encode(params, batch["audio_emb"], tuner=ctx.tuner)
+        enc = self.encode(params, batch["audio_emb"], tuner=ctx.tuner,
+                          backend=ctx.backend)
         x, _ = self._decode_seq(params, batch["tokens"], enc, ctx)
         return chunked_cross_entropy(x, params["embed"].T, batch["labels"])
 
     def prefill(self, params: dict, batch: dict, ctx: Ctx
                 ) -> tuple[jax.Array, list]:
-        enc = self.encode(params, batch["audio_emb"], tuner=ctx.tuner)
+        enc = self.encode(params, batch["audio_emb"], tuner=ctx.tuner,
+                          backend=ctx.backend)
         x, caches = self._decode_seq(params, batch["tokens"], enc, ctx)
         logits = jnp.einsum("bd,dv->bv", x[:, -1], params["embed"].T)
         return logits, caches

@@ -235,7 +235,8 @@ def chunked_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out[:, :, :sq]
 
 
-def attention_train(p: dict, x: jax.Array, s: AttnSpec, tuner=None
+def attention_train(p: dict, x: jax.Array, s: AttnSpec, tuner=None,
+                    backend: str = "auto"
                     ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
     """Full-sequence self-attention (training / prefill internals).
 
@@ -249,7 +250,8 @@ def attention_train(p: dict, x: jax.Array, s: AttnSpec, tuner=None
     XLA backend — whether the SYRK score-materialisation path wins for
     this shape (recorded as routine="syrk" through ops.syrk, like the
     retired fixed-threshold lowering).  Non-causal unwindowed layers
-    stay gemm-tagged.
+    stay gemm-tagged.  ``backend`` is passed to the dispatch (see
+    :attr:`repro.models.transformer.Ctx.backend`).
     """
     b, sq, _ = x.shape
     positions = jnp.arange(sq)
@@ -263,7 +265,7 @@ def attention_train(p: dict, x: jax.Array, s: AttnSpec, tuner=None
     out = ops.flash_attention(qt.reshape(flat), kt.reshape(flat),
                               vt.reshape(flat), causal=s.causal,
                               window=s.window, tuner=tuner,
-                              site="attn.core")
+                              backend=backend, site="attn.core")
     out = out.reshape(b, s.n_heads, sq, s.head_dim).transpose(0, 2, 1, 3)
     ops.observe(b * sq, s.n_heads * s.head_dim, x.shape[-1], tuner,
                 site="attn.out_proj")

@@ -93,7 +93,8 @@ def _shared_ffn(p: dict, xf: jax.Array, tuner=None) -> jax.Array:
 # Path 1: dense one-hot dispatch (small configs, pure jit)
 # ---------------------------------------------------------------------------
 
-def apply_moe(p: dict, x: jax.Array, s: MoESpec, tuner=None
+def apply_moe(p: dict, x: jax.Array, s: MoESpec, tuner=None,
+              backend: str = "auto"
               ) -> tuple[jax.Array, jax.Array]:
     """x: (B, S, D) -> (out, aux_loss).  One-hot einsum dispatch."""
     b, sl, d = x.shape
@@ -112,10 +113,12 @@ def apply_moe(p: dict, x: jax.Array, s: MoESpec, tuner=None
     disp_c = jax.nn.one_hot(jnp.where(keep, pos, cap), cap, dtype=x.dtype)
     buckets = jnp.einsum("td,tke,tkc->ecd", xf, disp_e, disp_c)
 
-    hi = ops.grouped_matmul(buckets, p["wi"], tuner=tuner, site="moe.wi")
-    hg = ops.grouped_matmul(buckets, p["wg"], tuner=tuner, site="moe.wg")
+    hi = ops.grouped_matmul(buckets, p["wi"], tuner=tuner,
+                            backend=backend, site="moe.wi")
+    hg = ops.grouped_matmul(buckets, p["wg"], tuner=tuner,
+                            backend=backend, site="moe.wg")
     y = ops.grouped_matmul(jax.nn.silu(hg) * hi, p["wo"], tuner=tuner,
-                           site="moe.wo")
+                           backend=backend, site="moe.wo")
 
     combine = disp_e * (gate_vals * keep).astype(x.dtype)[..., None]
     out = jnp.einsum("ecd,tke,tkc->td", y, combine, disp_c)
@@ -165,7 +168,8 @@ def _combine(y: jax.Array, dest: jax.Array, order: jax.Array,
     return jnp.einsum("tkd,tk->td", per_choice, w)
 
 
-def apply_moe_ep(p: dict, x: jax.Array, s: MoESpec, tuner=None
+def apply_moe_ep(p: dict, x: jax.Array, s: MoESpec, tuner=None,
+                 backend: str = "auto"
                  ) -> tuple[jax.Array, jax.Array]:
     """Expert-parallel MoE (n_experts divisible by the ep axis).
 
@@ -188,10 +192,12 @@ def apply_moe_ep(p: dict, x: jax.Array, s: MoESpec, tuner=None
     # (E, C, D) -> (E/ep, ep*C, D): rows for my local experts from all peers
     buckets = jax.lax.all_to_all(buckets, s.ep_axis, split_axis=0,
                                  concat_axis=1, tiled=True)
-    hi = ops.grouped_matmul(buckets, p["wi"], tuner=tuner, site="moe.wi")
-    hg = ops.grouped_matmul(buckets, p["wg"], tuner=tuner, site="moe.wg")
+    hi = ops.grouped_matmul(buckets, p["wi"], tuner=tuner,
+                            backend=backend, site="moe.wi")
+    hg = ops.grouped_matmul(buckets, p["wg"], tuner=tuner,
+                            backend=backend, site="moe.wg")
     y = ops.grouped_matmul(jax.nn.silu(hg) * hi, p["wo"], tuner=tuner,
-                           site="moe.wo")
+                           backend=backend, site="moe.wo")
     y = jax.lax.all_to_all(y, s.ep_axis, split_axis=1, concat_axis=0,
                            tiled=True)                     # (E, C, D)
 
@@ -201,7 +207,8 @@ def apply_moe_ep(p: dict, x: jax.Array, s: MoESpec, tuner=None
     return out.reshape(b, sl, d), aux
 
 
-def apply_moe_tp(p: dict, x: jax.Array, s: MoESpec, tuner=None
+def apply_moe_tp(p: dict, x: jax.Array, s: MoESpec, tuner=None,
+                 backend: str = "auto"
                  ) -> tuple[jax.Array, jax.Array]:
     """Expert-TP MoE for small expert counts (mixtral: 8 experts on a
     16-way model axis).  MUST run inside shard_map with ``x`` replicated
@@ -221,10 +228,11 @@ def apply_moe_tp(p: dict, x: jax.Array, s: MoESpec, tuner=None
 
     buckets, dest, order, valid = _dispatch(xf, gate_idx, s, cap)
     hi = ops.grouped_matmul(buckets, p["wi"], tuner=tuner,
-                            site="moe.wi")         # (E, C, F/tp)
-    hg = ops.grouped_matmul(buckets, p["wg"], tuner=tuner, site="moe.wg")
+                            backend=backend, site="moe.wi")  # (E, C, F/tp)
+    hg = ops.grouped_matmul(buckets, p["wg"], tuner=tuner,
+                            backend=backend, site="moe.wg")
     y = ops.grouped_matmul(jax.nn.silu(hg) * hi, p["wo"], tuner=tuner,
-                           site="moe.wo")          # partial sums
+                           backend=backend, site="moe.wo")  # partial sums
     y = jax.lax.psum(y, s.ep_axis)
 
     out = _combine(y, dest, order, valid, gate_vals, n_tok, s)

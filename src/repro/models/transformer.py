@@ -164,6 +164,20 @@ class Ctx:
                                    # sites still report dispatch events,
                                    # just untuned)
 
+    @property
+    def backend(self) -> str:
+        """Kernel backend (:func:`repro.kernels.ops.resolve_backend`) for
+        the call sites XLA partitions or differentiates.
+
+        Pallas kernels define no VJP, and Mosaic kernels cannot be
+        partitioned by GSPMD (only inside ``shard_map``), so train steps
+        and meshed steps take the XLA path; an unmeshed prefill or
+        decode resolves as usual (the tuned kernels on a TPU).
+        """
+        if self.mode == "train" or self.mesh is not None:
+            return "xla"
+        return "auto"
+
 
 def _moe_apply(p: dict, x: jax.Array, cfg: ArchConfig, ctx: Ctx
                ) -> tuple[jax.Array, jax.Array]:
@@ -176,9 +190,9 @@ def _moe_apply(p: dict, x: jax.Array, cfg: ArchConfig, ctx: Ctx
     """
     spec = _moe_spec(cfg)
     if ctx.mesh is None or ctx.mode == "decode":
-        return MOE.apply_moe(p, x, spec, tuner=ctx.tuner)
+        return MOE.apply_moe(p, x, spec, tuner=ctx.tuner,
+                             backend=ctx.backend)
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     dp = ctx.dp_axes
     tp = ctx.tp_axis
     tp_size = ctx.mesh.shape[tp]
@@ -186,9 +200,13 @@ def _moe_apply(p: dict, x: jax.Array, cfg: ArchConfig, ctx: Ctx
                and x.shape[1] % tp_size == 0)
     spec = dataclasses.replace(spec, ep_axis=tp)
     fn = MOE.apply_moe_ep if ep_mode else MOE.apply_moe_tp
+    # inside shard_map each device runs its own kernel call: only
+    # autodiff (train mode) still needs the XLA path
+    backend = "xla" if ctx.mode == "train" else "auto"
 
     def wrapped(p_local, x_local):
-        out, aux = fn(p_local, x_local, s=spec, tuner=ctx.tuner)
+        out, aux = fn(p_local, x_local, s=spec, tuner=ctx.tuner,
+                      backend=backend)
         return out, jax.lax.pmean(aux, (*dp, tp))
 
     if ep_mode:
@@ -205,11 +223,11 @@ def _moe_apply(p: dict, x: jax.Array, cfg: ArchConfig, ctx: Ctx
             else:
                 w_specs[k] = P(None, None, tp)
         x_spec = P(dp, None, None)
-    return shard_map(
+    return jax.shard_map(
         wrapped, mesh=ctx.mesh,
         in_specs=(w_specs, x_spec),
         out_specs=(x_spec, P()),
-        check_rep=False)(p, x)
+        check_vma=False)(p, x)
 
 
 def _seed_cache(raw: Any, cfg: ArchConfig, spec: LayerSpec,
@@ -245,7 +263,8 @@ def _apply_layer_train(p: dict, x: jax.Array, cfg: ArchConfig,
         else:
             mix, raw = L.attention_train(p["mixer"], h,
                                          _attn_spec(cfg, spec.kind),
-                                         tuner=ctx.tuner)
+                                         tuner=ctx.tuner,
+                                         backend=ctx.backend)
     elif spec.kind == "rglru":
         mix, raw = REC.rglru_block_train(p["mixer"], h)
     elif spec.kind == "mlstm":

@@ -3,13 +3,8 @@
 * Fast lane: ``pytest -m "not slow"`` skips the end-to-end install and
   subprocess-spawning distributed suites (the ``slow`` marker is
   registered in pyproject.toml).
-* ``hypothesis`` is a declared test dependency (pyproject ``[test]``
-  extra), but the hermetic CI container cannot pip-install it; when the
-  real package is missing, a deterministic fixed-seed fallback
-  (repro._compat.hypothesis_fallback) fills the import so the four
-  property-test modules still collect and run.
-* ``pytest-timeout`` is likewise declared but not installable here;
-  when missing, a SIGALRM fallback plugin
+* ``pytest-timeout`` is a declared test dependency; when it is
+  missing, a SIGALRM fallback plugin
   (repro._compat.pytest_timeout_fallback) enforces the suite's
   ``--timeout`` / ``@pytest.mark.timeout`` budgets so a wedged
   subprocess test fails instead of hanging the lane.
@@ -23,13 +18,6 @@ import pytest
 
 sys.path.insert(
     0, os.path.join(os.path.dirname(os.path.dirname(__file__)), "src"))
-
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    from repro._compat import hypothesis_fallback
-
-    hypothesis_fallback.install()
 
 try:
     import pytest_timeout  # noqa: F401

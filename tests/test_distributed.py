@@ -37,6 +37,7 @@ def _run(body: str) -> str:
             "--xla_force_host_platform_device_count={_DEVICES}"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.launch.mesh import make_mesh
         MESH_A = {_MESH_A!r}
         MESH_B = {_MESH_B!r}
     """) + textwrap.dedent(body)
@@ -61,9 +62,8 @@ def test_moe_ep_matches_dense():
         from repro.models.moe import (MoESpec, moe_defs, apply_moe,
                                       apply_moe_ep)
         from repro.models.params import init_params
-        from jax.experimental.shard_map import shard_map
 
-        mesh = jax.make_mesh(MESH_A, ("data", "model"))
+        mesh = make_mesh(MESH_A, ("data", "model"))
         s = MoESpec(d_model=32, n_experts=8, top_k=2, d_ff=64,
                     capacity_factor=8.0, ep_axis="model")
         p = init_params(moe_defs(s), jax.random.PRNGKey(0))
@@ -76,10 +76,10 @@ def test_moe_ep_matches_dense():
             return out, jax.lax.pmean(aux, ("data", "model"))
         w_specs = {k: (P() if k.startswith(("router", "shared"))
                        else P("model", None, None)) for k in p}
-        ep_out, ep_aux = jax.jit(shard_map(
+        ep_out, ep_aux = jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=(w_specs, P("data", "model", None)),
             out_specs=(P("data", "model", None), P()),
-            check_rep=False))(p, x)
+            check_vma=False))(p, x)
         err = float(jnp.abs(dense_out - ep_out).max())
         # EP routes per-shard (local top-k == global top-k for the same
         # tokens); with no capacity drops outputs must match exactly
@@ -95,9 +95,8 @@ def test_moe_tp_matches_dense():
         from repro.models.moe import (MoESpec, moe_defs, apply_moe,
                                       apply_moe_tp)
         from repro.models.params import init_params
-        from jax.experimental.shard_map import shard_map
 
-        mesh = jax.make_mesh(MESH_A, ("data", "model"))
+        mesh = make_mesh(MESH_A, ("data", "model"))
         s = MoESpec(d_model=32, n_experts=6, top_k=2, d_ff=64,
                     capacity_factor=8.0, ep_axis="model")
         p = init_params(moe_defs(s), jax.random.PRNGKey(0))
@@ -115,10 +114,10 @@ def test_moe_tp_matches_dense():
                 w_specs[k] = P(None, "model", None)
             else:
                 w_specs[k] = P(None, None, "model")
-        tp_out, _ = jax.jit(shard_map(
+        tp_out, _ = jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=(w_specs, P("data", None, None)),
             out_specs=(P("data", None, None), P()),
-            check_rep=False))(p, x)
+            check_vma=False))(p, x)
         err = float(jnp.abs(dense_out - tp_out).max())
         assert err < 1e-4, err
     """)
@@ -133,7 +132,7 @@ def test_sharded_train_step_runs():
         from repro.train.step import build_train_step, init_train_state
         from repro.models.config import ShapeSpec
 
-        mesh = jax.make_mesh(MESH_A, ("data", "model"))
+        mesh = make_mesh(MESH_A, ("data", "model"))
         cfg = get_smoke_config("granite-8b")
         model = build_model(cfg)
         shape = ShapeSpec("t", 32, 4, "train")
@@ -166,8 +165,8 @@ def test_elastic_checkpoint_reshard():
         import tempfile
         from repro.ckpt.checkpoint import (save_checkpoint,
                                            restore_checkpoint)
-        mesh_a = jax.make_mesh(MESH_A, ("data", "model"))
-        mesh_b = jax.make_mesh(MESH_B, ("data", "model"))
+        mesh_a = make_mesh(MESH_A, ("data", "model"))
+        mesh_b = make_mesh(MESH_B, ("data", "model"))
         w = jax.device_put(
             jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
             NamedSharding(mesh_a, P("data", "model")))

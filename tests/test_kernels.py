@@ -278,3 +278,15 @@ def test_resolve_backend_env_override(monkeypatch):
     monkeypatch.setenv("ADSALA_BACKEND", "mosaic")
     with pytest.raises(ValueError, match="ADSALA_BACKEND"):
         resolve_backend("auto")
+
+
+def test_resolve_interpret_only_on_cpu(monkeypatch):
+    """Interpret mode engages only where JAX's platform is the CPU; an
+    explicit choice wins everywhere."""
+    from repro.kernels import ops
+    assert ops.resolve_interpret() is True          # this suite: cpu
+    assert ops.resolve_interpret(False) is False
+    for platform, want in (("tpu", False), ("gpu", False), ("cpu", True)):
+        monkeypatch.setattr(ops.jax, "default_backend", lambda p=platform: p)
+        assert ops.resolve_interpret() is want
+        assert ops.resolve_interpret(True) is True

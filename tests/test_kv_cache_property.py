@@ -1,10 +1,5 @@
 """Property tests for the paged-KV page allocator.
 
-Runs under real `hypothesis` or the deterministic
-``repro._compat.hypothesis_fallback`` shim (fixed-seed example sweeps) —
-only ``integers`` / ``sampled_from`` / ``lists`` strategies and
-``given``/``settings`` are used.
-
 The allocator contract the continuous-batching scheduler leans on:
 
 * a live page is never handed out twice;

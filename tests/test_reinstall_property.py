@@ -7,11 +7,6 @@
 * the DriftTrigger hysteresis invariant: no two fires within the
   cooldown, regardless of the drift trajectory, and a second fire
   requires re-arming below threshold - hysteresis.
-
-Runs under real `hypothesis` or the deterministic
-``repro._compat.hypothesis_fallback`` shim (fixed-seed example sweeps)
-— only ``integers`` / ``floats`` / ``lists`` strategies and
-``given``/``settings`` are used.
 """
 
 import numpy as np

@@ -1,10 +1,4 @@
-"""Property tests for the BLAS-3 routine cost model and tuner plumbing.
-
-Runs under real `hypothesis` or the deterministic
-``repro._compat.hypothesis_fallback`` shim (fixed-seed example sweeps) —
-only ``integers`` / ``sampled_from`` strategies and ``given``/``settings``
-are used.
-"""
+"""Property tests for the BLAS-3 routine cost model and tuner plumbing."""
 
 import numpy as np
 import pytest

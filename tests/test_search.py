@@ -8,10 +8,6 @@ Three contracts anchor the refactor:
   for every routine (ties included — first-occurrence order);
 * a narrow beam over the ~11x enlarged space finds the optimum while
   pricing a small fraction of it (the smoke benchmark's claim).
-
-Runs under real `hypothesis` or the deterministic
-``repro._compat.hypothesis_fallback`` shim — only ``integers`` /
-``sampled_from`` strategies and ``given``/``settings`` are used.
 """
 
 import json
