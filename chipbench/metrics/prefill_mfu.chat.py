@@ -1,0 +1,16 @@
+"""Prefill's share of the chip's peak while it runs: the prompts'
+operations (flops.py) over the device time of the prefill programs
+times the bf16 peak. Read only where the trace holds as many prefill
+runs as the harness admitted."""
+
+import flops
+
+
+def read(run):
+    runs = [m for kind, m in run.trace.programs() if kind == "prefill"]
+    lengths = run.prefill_lengths()
+    if not runs or len(runs) != len(lengths):
+        return None
+    f = sum(flops.prefill_flops(run.k, n) for n in lengths)
+    t = sum(m.dur for m in runs) / 1e9
+    return 100.0 * f / (t * run.peak["bf16_flops"])
