@@ -283,9 +283,10 @@ def serve_phase(argv: list[str]) -> dict:
     n_req = int(argv[argv.index("--requests") + 1])
     done = len(res["finished"])
     print(f"[serve] {done}/{n_req} requests finished, alloc.check() "
-          f"passed; {res['tokens']} tokens in {res['wall_s']:.6f}s = "
-          f"{res['tok_s']:.6f} tok/s (smoke number on {kind}, compiles "
-          f"included)", flush=True)
+          f"passed; {res['tokens']} tokens, time to first token p90 "
+          f"{res['ttft_ms']['p90']:.6f} ms, gap between tokens p90 "
+          f"{res['itl_ms']['p90']:.6f} ms (smoke numbers on {kind}, "
+          f"compiles included)", flush=True)
     if done != n_req:
         raise AssertionError(f"{done} of {n_req} requests finished")
     return res

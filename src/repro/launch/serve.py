@@ -299,12 +299,37 @@ def _report_tail(args, cfg, recs, tuner, manager) -> None:
         print(f"[serve] workload profile written to {args.profile_out}")
 
 
+def _latency_ms(finished: dict) -> dict:
+    """p50 / p90 (ms) and sample count of queue wait, time to first
+    token and the gap between consecutive tokens, from the scheduler's
+    own timestamps (:class:`~repro.serve.scheduler.FinishedSeq`)."""
+    import numpy as np
+
+    fs = list(finished.values())
+    samples = {
+        "queue_wait_ms": [f.admitted_at - f.submitted_at for f in fs],
+        "ttft_ms": [f.token_times[0] - f.submitted_at for f in fs],
+        "itl_ms": [b - a for f in fs
+                   for a, b in zip(f.token_times, f.token_times[1:])],
+    }
+    out = {}
+    for name, xs in samples.items():
+        ms = 1e3 * np.asarray(xs, np.float64)
+        out[name] = {"p50": float(np.percentile(ms, 50)) if xs else None,
+                     "p90": float(np.percentile(ms, 90)) if xs else None,
+                     "n": len(xs)}
+    return out
+
+
 def _serve_queue(args, cfg, model, params, tuner, manager, recs) -> dict:
     """Trace-driven continuous batching: ragged requests through the
     paged-KV scheduler, re-install drift checks riding the step hook.
 
-    Returns ``{"finished": {rid: FinishedSeq}, "tokens", "wall_s",
-    "tok_s"}``; the wall time includes every compile inside the run.
+    Returns ``{"finished": {rid: FinishedSeq}, "tokens", "queue_wait_ms",
+    "ttft_ms", "itl_ms"}``, each latency ``{"p50", "p90", "n"}`` from
+    the scheduler's timestamps (:func:`_latency_ms`). Every request is
+    queued at the start and each prompt length compiles its prefill on
+    first use, so queue wait and time to first token include compiles.
     """
     import numpy as np
 
@@ -334,24 +359,27 @@ def _serve_queue(args, cfg, model, params, tuner, manager, recs) -> dict:
                   f"threshold at decode step {s.steps}: background "
                   "re-install launched (serving continues)")
 
-    t0 = time.perf_counter()
     finished = sched.run_until_drained(on_step=on_step)
-    wall = time.perf_counter() - t0
 
     toks = sum(len(f.tokens) for f in finished.values())
-    tps = toks / max(wall, 1e-9)
+    lat = _latency_ms(finished)
     print(f"[serve] {cfg.name}: {len(finished)} requests via "
           f"continuous batching ({args.slots} slots, {n_pages} pages x "
-          f"{args.page_size} tokens), {toks} tokens in {wall*1e3:.1f}ms "
-          f"({tps:.1f} tok/s), goodput {sched.goodput():.3f} "
-          f"tok/slot-step over {sched.steps} steps")
+          f"{args.page_size} tokens), {toks} tokens generated, goodput "
+          f"{sched.goodput():.3f} tok/slot-step over {sched.steps} steps")
+    for name, what in (("queue_wait_ms", "queue wait"),
+                       ("ttft_ms", "time to first token"),
+                       ("itl_ms", "gap between tokens")):
+        q = lat[name]
+        if q["n"]:
+            print(f"[serve] {what}: p50 {q['p50']:.3f} ms, p90 "
+                  f"{q['p90']:.3f} ms over {q['n']}")
     sample = min(finished)
     print(f"[serve] sample continuation ids: "
           f"{list(finished[sample].tokens)[:8]}")
     sched.alloc.check()
     _report_tail(args, cfg, recs, tuner, manager)
-    return {"finished": finished, "tokens": toks, "wall_s": wall,
-            "tok_s": tps}
+    return {"finished": finished, "tokens": toks, **lat}
 
 
 if __name__ == "__main__":

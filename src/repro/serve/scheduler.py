@@ -29,18 +29,36 @@ split :mod:`repro.launch.serve` feeds the
 mix drives online re-installs unchanged.  Recording is trace-time: a
 new prompt length is a new prefill trace, so the recorded mix tracks
 the shape diversity actually admitted.
+
+Timing: every request carries host timestamps (``time.perf_counter``)
+of its submission, its admission and the moment each generated token's
+value reached the host (:class:`FinishedSeq`). Under JAX's profiler the
+scheduler's phases are host spans on the trace's clock, each with
+numeric stats; spans of one request share its ``rid``:
+
+- ``serve.step`` (``step``, ``active``, ``pending``): one ``step()``;
+- ``serve.admit`` (``rid``, ``prompt_len``, ``queue_wait_ms``): one
+  admission, from taking the request off the queue to its first token
+  on the host, holding ``serve.prefill`` (``rid``, ``prompt_len``) and
+  ``serve.seed_pages`` (``rid``, ``pages``);
+- ``serve.decode`` (``active``): the decode dispatch through the fetch
+  of the next tokens.
+
+With the profiler off a span costs about a microsecond.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import threading
+import time
 from collections import deque
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serve.kv_cache import (
     HOLE,
@@ -59,17 +77,26 @@ class Request:
     rid: int
     prompt: tuple[int, ...]
     max_new: int
+    submitted_at: float        # host clock (``time.perf_counter``)
 
 
 @dataclasses.dataclass(frozen=True)
 class FinishedSeq:
-    """A retired sequence: the generated ids plus scheduling metadata."""
+    """A retired sequence: the generated ids plus scheduling metadata.
+
+    Times are host-clock seconds (``time.perf_counter``): queue wait is
+    ``admitted_at - submitted_at``, time to first token
+    ``token_times[0] - submitted_at``; ``token_times[i]`` is when
+    ``tokens[i]`` reached the host."""
 
     rid: int
     prompt: tuple[int, ...]
     tokens: tuple[int, ...]
     admitted_step: int
     finished_step: int
+    submitted_at: float
+    admitted_at: float
+    token_times: tuple[float, ...]
 
 
 def _seed_segment(pool_seg: Any, cache_seg: Any, page_ids: jax.Array,
@@ -159,7 +186,9 @@ class ContinuousBatchingScheduler:
         self._tok = np.zeros((slots,), np.int32)
         self._req: list[Request | None] = [None] * slots
         self._gen: list[list[int]] = [[] for _ in range(slots)]
+        self._times: list[list[float]] = [[] for _ in range(slots)]
         self._admit_step = [0] * slots
+        self._admitted_at = [0.0] * slots
 
         self._lock = threading.Lock()
         self._queue: deque[Request] = deque()
@@ -204,7 +233,8 @@ class ContinuousBatchingScheduler:
             if rid in self._rids:
                 raise ValueError(f"duplicate rid {rid}")
             self._rids.add(rid)
-            self._queue.append(Request(rid, prompt, max_new))
+            self._queue.append(
+                Request(rid, prompt, max_new, time.perf_counter()))
         return rid
 
     @property
@@ -250,32 +280,43 @@ class ContinuousBatchingScheduler:
             self._start(slot, req, pages)
 
     def _start(self, slot: int, req: Request, pages: list[int]) -> None:
-        n_prompt_pages = pages_for(len(req.prompt), self.page_size)
-        toks = jnp.asarray(np.asarray(req.prompt, np.int32)[None, :])
-        with self.recorders["prefill"]:
-            logits, cache = self._prefill_fn(len(req.prompt))(
-                self.params, toks)
-        page_ids = jnp.asarray(
-            np.asarray(pages[:n_prompt_pages], np.int32))
-        pool = self.pool
-        self.pool = {
-            "prefix": _seed_segment(pool["prefix"], cache["prefix"],
-                                    page_ids, stacked=False),
-            "scan": (_seed_segment(pool["scan"], cache["scan"],
-                                   page_ids, stacked=True)
-                     if self.model.repeats else pool["scan"]),
-            "suffix": _seed_segment(pool["suffix"], cache["suffix"],
-                                    page_ids, stacked=False),
-        }
-        first = int(jnp.argmax(logits[0]))
+        admitted_at = time.perf_counter()
+        n_prompt = len(req.prompt)
+        with TraceAnnotation(
+                "serve.admit", rid=req.rid, prompt_len=n_prompt,
+                queue_wait_ms=1e3 * (admitted_at - req.submitted_at)):
+            n_prompt_pages = pages_for(n_prompt, self.page_size)
+            toks = jnp.asarray(np.asarray(req.prompt, np.int32)[None, :])
+            with TraceAnnotation("serve.prefill", rid=req.rid,
+                                 prompt_len=n_prompt), \
+                    self.recorders["prefill"]:
+                logits, cache = self._prefill_fn(n_prompt)(
+                    self.params, toks)
+            with TraceAnnotation("serve.seed_pages", rid=req.rid,
+                                 pages=n_prompt_pages):
+                page_ids = jnp.asarray(
+                    np.asarray(pages[:n_prompt_pages], np.int32))
+                pool = self.pool
+                self.pool = {
+                    "prefix": _seed_segment(pool["prefix"], cache["prefix"],
+                                            page_ids, stacked=False),
+                    "scan": (_seed_segment(pool["scan"], cache["scan"],
+                                           page_ids, stacked=True)
+                             if self.model.repeats else pool["scan"]),
+                    "suffix": _seed_segment(pool["suffix"], cache["suffix"],
+                                            page_ids, stacked=False),
+                }
+            first = int(jnp.argmax(logits[0]))
+        self._times[slot] = [time.perf_counter()]
         row = np.full((self.table_pages,), HOLE, np.int32)
         row[: len(pages)] = pages
         self._table[slot] = row
-        self._pos[slot] = len(req.prompt)   # next decode writes here
+        self._pos[slot] = n_prompt          # next decode writes here
         self._tok[slot] = first
         self._req[slot] = req
         self._gen[slot] = [first]
         self._admit_step[slot] = self.steps
+        self._admitted_at[slot] = admitted_at
         self.admitted += 1
         if req.max_new == 1 or first == self.eos_id:
             self._retire(slot)              # finished at prefill
@@ -286,14 +327,23 @@ class ContinuousBatchingScheduler:
 
         Returns False when there was nothing to do (no active slots
         after admission)."""
+        with TraceAnnotation("serve.step", step=self.steps,
+                             active=self.active, pending=self.pending):
+            return self._step()
+
+    def _step(self) -> bool:
         self._admit()
-        if self.active == 0:
+        active = self.active
+        if active == 0:
             return False
-        with self.recorders["decode"]:
-            logits, self.pool = self._decode(
-                self.params, self.pool, jnp.asarray(self._tok[:, None]),
-                jnp.asarray(self._pos), jnp.asarray(self._table))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        with TraceAnnotation("serve.decode", active=active):
+            with self.recorders["decode"]:
+                logits, self.pool = self._decode(
+                    self.params, self.pool,
+                    jnp.asarray(self._tok[:, None]),
+                    jnp.asarray(self._pos), jnp.asarray(self._table))
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        now = time.perf_counter()
         self.steps += 1
         for i in range(self.slots):
             req = self._req[i]
@@ -301,6 +351,7 @@ class ContinuousBatchingScheduler:
                 continue
             tok = int(nxt[i])
             self._gen[i].append(tok)
+            self._times[i].append(now)
             self._pos[i] += 1
             self._tok[i] = tok
             if len(self._gen[i]) >= req.max_new or tok == self.eos_id:
@@ -316,12 +367,14 @@ class ContinuousBatchingScheduler:
                 len(req.prompt) + req.max_new - 1, self.page_size)
             self.finished[req.rid] = FinishedSeq(
                 req.rid, req.prompt, tuple(self._gen[slot]),
-                self._admit_step[slot], self.steps)
+                self._admit_step[slot], self.steps, req.submitted_at,
+                self._admitted_at[slot], tuple(self._times[slot]))
         self._table[slot] = HOLE
         self._pos[slot] = -1
         self._tok[slot] = 0
         self._req[slot] = None
         self._gen[slot] = []
+        self._times[slot] = []
 
     def run_until_drained(self, on_step: Callable | None = None,
                           max_steps: int = 1_000_000

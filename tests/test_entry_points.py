@@ -99,7 +99,12 @@ def test_serve_main_argv_queue(monkeypatch, tmp_path):
     assert len(res["finished"]) == 3
     assert res["tokens"] == sum(len(f.tokens)
                                 for f in res["finished"].values())
-    assert res["tok_s"] > 0
+    # latencies come from the scheduler's timestamps
+    assert res["queue_wait_ms"]["n"] == res["ttft_ms"]["n"] == 3
+    assert res["itl_ms"]["n"] == res["tokens"] - 3
+    for name in ("queue_wait_ms", "ttft_ms"):
+        assert 0 <= res[name]["p50"] <= res[name]["p90"]
+    assert res["ttft_ms"]["p50"] >= res["queue_wait_ms"]["p50"]
 
 
 def test_chip_smoke_fails_without_a_chip():
