@@ -4,7 +4,8 @@ decode), chunked-softmax attention for long sequences.
 All functions are pure; parameters arrive as dicts produced from the
 ParamDef trees in each block builder.  Activations are (B, S, D); the
 attention entry points switch between the Pallas flash kernel and the
-chunked XLA path via repro.kernels.ops.
+chunked XLA path via repro.kernels.ops, and paged decode between the
+Pallas paged kernel (repro.kernels.paged_attention) and an XLA gather.
 """
 
 from __future__ import annotations
@@ -16,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
+from repro.kernels.paged_attention import paged_decode_attention_pallas
 from repro.models.params import ParamDef
 
 __all__ = [
@@ -414,7 +416,7 @@ def attention_decode(p: dict, x: jax.Array, s: AttnSpec, cache: KVCache,
 
 def attention_decode_paged(p: dict, x: jax.Array, s: AttnSpec, pool,
                            page_table: jax.Array, pos: jax.Array,
-                           tuner=None):
+                           tuner=None, backend: str = "auto"):
     """One-token decode against a paged KV pool (continuous batching).
 
     x (B, 1, D); ``pos`` is (B,) int32 — every sequence in the batch
@@ -423,15 +425,22 @@ def attention_decode_paged(p: dict, x: jax.Array, s: AttnSpec, pool,
     sequence's logical pages to physical pages of ``pool``
     (:class:`repro.serve.kv_cache.PagedKV`), -1 marking holes.
 
-    The compute is element-for-element the fixed-batch
+    Where ``backend`` resolves to ``"pallas"`` the attention core is
+    :func:`repro.kernels.paged_attention.paged_decode_attention_pallas`:
+    it reads only each slot's live pages, in the pool's dtype, and
+    scores the G query heads of a KV head against one load of its rows.
+    Its arithmetic is the XLA path's at f32, with the softmax summed
+    block by block, so the two agree to f32 rounding.
+
+    The XLA path is element-for-element the fixed-batch
     :func:`attention_decode`: the page gather materialises the same
     (B, cap, Hkv, Dh) view the contiguous cache holds (holes land
     beyond the ``kv_ids <= pos`` valid prefix where the mask erases
-    them), so per-sequence outputs are bitwise identical to the
-    fixed-batch path — the scheduler's golden-parity contract.  The
-    cache update keeps its TRSM-site recorder tag: still a sequential
-    append + triangular-prefix read, just scattered through the page
-    table.
+    them), so on this path per-sequence outputs are bitwise identical
+    to the fixed-batch path — the scheduler's golden-parity contract.
+    The cache update keeps its TRSM-site recorder tag: still a
+    sequential append + triangular-prefix read, just scattered through
+    the page table.
     """
     from repro.serve.kv_cache import append_token, gather_pages
 
@@ -445,18 +454,26 @@ def attention_decode_paged(p: dict, x: jax.Array, s: AttnSpec, pool,
                 routine="trsm", site="attn.cache_update")
     active = pos >= 0
     pool = type(pool)(
-        append_token(pool.k, page_table, pos, k_new[:, 0], active),
-        append_token(pool.v, page_table, pos, v_new[:, 0], active))
-    k = gather_pages(pool.k, page_table)     # (B, cap, Hkv, Dh)
-    v = gather_pages(pool.v, page_table)
-    kk = _repeat_kv(k, s.n_heads)
-    vv = _repeat_kv(v, s.n_heads)
-    scores = jnp.einsum("bohd,bkhd->bhk", q.astype(jnp.float32),
-                        kk.astype(jnp.float32)) * (s.head_dim ** -0.5)
-    valid = jnp.arange(cap)[None, :] <= pos[:, None]
-    scores = jnp.where(valid[:, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhk,bkhd->bhd", probs, vv.astype(jnp.float32))
+        append_token(pool.k, page_table, pos, k_new[:, 0].reshape(b, -1),
+                     active),
+        append_token(pool.v, page_table, pos, v_new[:, 0].reshape(b, -1),
+                     active))
+    if ops.resolve_backend(backend) == "pallas":
+        out = paged_decode_attention_pallas(
+            q[:, 0], pool.k, pool.v, jnp.where(active, pos + 1, 0),
+            page_table, interpret=ops.resolve_interpret())
+    else:
+        kv = (b, cap, s.n_kv_heads, s.head_dim)
+        k = gather_pages(pool.k, page_table).reshape(kv)
+        v = gather_pages(pool.v, page_table).reshape(kv)
+        kk = _repeat_kv(k, s.n_heads)
+        vv = _repeat_kv(v, s.n_heads)
+        scores = jnp.einsum("bohd,bkhd->bhk", q.astype(jnp.float32),
+                            kk.astype(jnp.float32)) * (s.head_dim ** -0.5)
+        valid = jnp.arange(cap)[None, :] <= pos[:, None]
+        scores = jnp.where(valid[:, None, :], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bhk,bkhd->bhd", probs, vv.astype(jnp.float32))
     out = out.reshape(b, 1, s.n_heads * s.head_dim).astype(x.dtype)
     ops.observe(b, s.n_heads * s.head_dim, x.shape[-1], tuner,
                 site="attn.out_proj")

@@ -351,7 +351,8 @@ def _apply_layer_decode(p: dict, x: jax.Array, cache: Any,
             else:
                 mix, cache = L.attention_decode_paged(
                     p["mixer"], h, _attn_spec(cfg, spec.kind), cache,
-                    page_table, pos, tuner=ctx.tuner)
+                    page_table, pos, tuner=ctx.tuner,
+                    backend=ctx.backend)
         elif cfg.attn_kind == "mla":
             mix, cache = MLA.mla_decode(p["mixer"], h, _mla_spec(cfg),
                                         cache, pos, tuner=ctx.tuner)

@@ -18,17 +18,18 @@ Split of responsibilities:
   free + live conservation, clean failure on exhaustion) are the
   property-tested contract (tests/test_kv_cache_property.py).
 * :class:`PagedKV` / :class:`PagedLatent` — registered pytrees holding
-  one attention layer's page pool: ``(n_pages, page_size, ...)``
+  one attention layer's page pool: ``(n_pages, page_size, width)``
   arrays, the direct paged analogue of
   :class:`~repro.models.layers.KVCache` and
   :class:`~repro.models.mla.MLACache`.
 * :func:`gather_pages` / :func:`append_token` / :func:`seed_pages` —
-  the jittable fixed-shape device primitives the paged decode read
+  the jittable fixed-shape device primitives the XLA paged decode
   path (``attention_decode_paged`` / ``mla_decode_paged``) is built
-  from.  Holes in the page table are clamped on gather (the garbage
-  rows land beyond every sequence's valid prefix, where the attention
-  mask kills them) and routed out of bounds on scatter (dropped, never
-  corrupting a live page).
+  from; the Pallas decode kernel (:mod:`repro.kernels.paged_attention`)
+  reads live pages itself.  Holes in the page table are clamped on
+  gather (the garbage rows land beyond every sequence's valid prefix,
+  where the attention mask kills them) and routed out of bounds on
+  scatter (dropped, never corrupting a live page).
 
 Sharding: pools carry no batch dim — the page dim takes the
 data-parallel axes and the head/width dim the model axis, both on the
@@ -170,14 +171,18 @@ class PageAllocator:
 class PagedKV:
     """One attention layer's page pool — the paged
     :class:`~repro.models.layers.KVCache`.  ``k``/``v``:
-    ``(n_pages, page_size, n_kv_heads, head_dim)``."""
+    ``(n_pages, page_size, n_kv_heads * head_dim)``: a token's KV heads
+    side by side in one row, so a page is one dense, lane-aligned block
+    on a TPU whatever the head width (a ``head_dim`` of 64 as its own
+    minor dim would be padded to 128 lanes, which the Pallas decode
+    kernel's page DMAs cannot slice)."""
 
     k: jax.Array
     v: jax.Array
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[-3]
+        return self.k.shape[-2]
 
 
 @dataclasses.dataclass
@@ -204,7 +209,7 @@ jax.tree_util.register_dataclass(PagedLatent,
 
 def init_paged_kv(n_pages: int, page_size: int, n_kv_heads: int,
                   head_dim: int, dtype: jnp.dtype) -> PagedKV:
-    shape = (n_pages, page_size, n_kv_heads, head_dim)
+    shape = (n_pages, page_size, n_kv_heads * head_dim)
     return PagedKV(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
@@ -226,7 +231,10 @@ def gather_pages(pages: jax.Array, table: jax.Array) -> jax.Array:
     Holes (:data:`HOLE`) clamp to page 0; whatever that page holds
     lands at token slots at/after the sequence's allocated prefix,
     where the downstream ``kv_ids <= pos`` attention mask zeroes it —
-    the gathered view is bitwise-safe without a select."""
+    the gathered view needs no select, and the XLA decode path built on
+    it stays bitwise equal to the fixed-batch path (the Pallas decode
+    kernel agrees with both to f32 rounding only).  The gather reads
+    the whole capped span of every slot, live or not."""
     b, t = table.shape
     page = pages.shape[1]
     gathered = jnp.take(pages, jnp.clip(table, 0, pages.shape[0] - 1),
@@ -260,7 +268,7 @@ def seed_pages(pages: jax.Array, page_ids: jax.Array,
 
     ``values``: ``(n * page, ...)`` contiguous token rows (pad to a
     page multiple first), scattered as ``n`` whole pages at
-    ``page_ids``."""
+    ``page_ids``; each row is flattened to the pool's row width."""
     n = page_ids.shape[0]
     page = pages.shape[1]
     vals = values.reshape((n, page) + pages.shape[2:])

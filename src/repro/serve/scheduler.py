@@ -41,8 +41,12 @@ numeric stats; spans of one request share its ``rid``:
   admission, from taking the request off the queue to its first token
   on the host, holding ``serve.prefill`` (``rid``, ``prompt_len``) and
   ``serve.seed_pages`` (``rid``, ``pages``);
-- ``serve.decode`` (``active``): the decode dispatch through the fetch
-  of the next tokens.
+- ``serve.decode`` (``active``, ``live_pages``): the decode dispatch
+  through the fetch of the next tokens; ``live_pages`` counts the pages
+  one layer's attention reads in the step, ``ceil((pos + 1) / page)``
+  summed over the active slots (the Pallas decode kernel reads only
+  those; ``live_pages / (active * table_pages)`` is the share of the
+  capped span actually read).
 
 With the profiler off a span costs about a microsecond.
 """
@@ -336,7 +340,9 @@ class ContinuousBatchingScheduler:
         active = self.active
         if active == 0:
             return False
-        with TraceAnnotation("serve.decode", active=active):
+        live = self._pos[self._pos >= 0] // self.page_size + 1
+        with TraceAnnotation("serve.decode", active=active,
+                             live_pages=int(live.sum())):
             with self.recorders["decode"]:
                 logits, self.pool = self._decode(
                     self.params, self.pool,

@@ -95,11 +95,15 @@ def test_profiler_trace_holds_the_spans(built, tmp_path):
     for name, keys in (("serve.step", {"step", "active", "pending"}),
                        ("serve.prefill", {"rid", "prompt_len"}),
                        ("serve.seed_pages", {"rid", "pages"}),
-                       ("serve.decode", {"active"})):
+                       ("serve.decode", {"active", "live_pages"})):
         stats = [st for n, st, *_ in evs if n == name]
         assert stats and all(set(st) == keys for st in stats), name
         assert all(isinstance(v, (int, float)) for st in stats
                    for v in st.values())
+    # every active slot reads at least its first page, at most a row
+    for st in (st for n, st, *_ in evs if n == "serve.decode"):
+        assert st["active"] <= st["live_pages"] \
+            <= st["active"] * s.table_pages
     # prefill and page seeding nest inside their request's admission
     spans = {(n, st.get("rid")): (t, t + d) for n, st, t, d in evs
              if n in ("serve.admit", "serve.prefill", "serve.seed_pages")}

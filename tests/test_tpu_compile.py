@@ -112,3 +112,81 @@ def test_flash_blocks_compile_at_ragged_prompt(one_chip, block, grid):
         (shape, jnp.float32))
     assert "tpu_custom_call" in hlo
 
+
+
+def _devtrace():
+    """The on-chip benchmark's trace reduction (``chipbench/devtrace.py``),
+    whose names tell prefill programs from decode programs."""
+    import importlib.util
+    import sys
+    from pathlib import Path
+
+    name = "chipbench_devtrace"
+    if name not in sys.modules:
+        path = Path(__file__).resolve().parents[1] / "chipbench" \
+            / "devtrace.py"
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod        # dataclasses resolve it by name
+        spec.loader.exec_module(mod)
+    return sys.modules[name]
+
+
+@pytest.mark.parametrize("arch,slots,cap,pages", [
+    ("granite-8b", 16, 2048, 1280),        # GQA 4:1, 8 KV heads of 128
+    ("stablelm-1.6b", 16, 1536, 1152),     # MHA, 32 KV heads of 64
+])
+def test_paged_decode_step_compiles_with_the_kernel(one_chip, monkeypatch,
+                                                    arch, slots, cap,
+                                                    pages):
+    """The serving decode step at a cell's attention shapes, two layers:
+    paged attention is one Mosaic kernel, nothing of the capped span is
+    widened to f32, and the kernel's names are none the benchmark takes
+    for the prefill's flash kernel."""
+    import dataclasses
+    import re
+
+    from repro.configs import build_model, get_config
+    from repro.kernels import ops
+    from repro.train.step import make_ctx
+
+    monkeypatch.setenv("ADSALA_BACKEND", "pallas")
+    monkeypatch.setattr(ops, "resolve_interpret", lambda interpret=None:
+                        False)
+    model = build_model(dataclasses.replace(get_config(arch), n_layers=2))
+    ctx = make_ctx(None, "decode", cache_len=cap)
+    table = cap // 16
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.bfloat16)))
+    pool = jax.tree.map(sds, jax.eval_shape(
+        lambda: model.init_paged_cache(pages, 16, ctx, jnp.bfloat16)))
+
+    def step(p, pool, tok, pos, tab):
+        return model.decode_step(p, tok, pool, pos, ctx, tab)
+
+    ints = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+            for s in ((slots, 1), (slots,), (slots, table))]
+    jitted = jax.jit(step, donate_argnums=(1,))
+    hlo = jitted.lower(params, pool, *ints).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    assert not re.search(rf"f32\[{slots},{cap}[,\]]", hlo)
+
+    def kernels(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e.params["jaxpr"].debug_info.func_name
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from kernels(sub)
+
+    names = list(kernels(jax.make_jaxpr(step)(params, pool, *ints).jaxpr))
+    assert names == ["_paged_decode_kernel"]
+    dt = _devtrace()
+    for text in names + [line.split("=")[0] for line in calls]:
+        assert not any(m in text for m in dt.FLASH_MARKS), text
+        assert dt.FLASH_JIT not in text, text
