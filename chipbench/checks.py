@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from reference.decoder import Reference, served_gaps
+from reference.common import served_gaps
 from traffic import seed_rng
 
 __all__ = ["sample", "compare"]
@@ -38,12 +38,13 @@ def sample(log, rids: list[int], seed: int, min_tokens: int,
     return pick
 
 
-def compare(k: dict, seed: int, log, rids: list[int], control: bool = False,
+def compare(cell, seed: int, log, rids: list[int], control: bool = False,
             length: int = 0) -> dict:
-    """Widest served gap over the sampled requests (and the control's,
-    read at the same positions, with ``control``). Every sequence is
-    padded to ``length``, so the reference compiles once per cell."""
-    ref = Reference(k, seed)
+    """Widest served gap over the sampled requests against the cell's
+    family's reference (and the control's, read at the same positions,
+    with ``control``). Every sequence is padded to ``length``, so the
+    reference compiles once per cell."""
+    ref = cell.family.Reference(cell.k, seed)
     served, ctrl, n = 0.0, 0.0, 0
     for rid in rids:
         r = log.reqs[rid]

@@ -2,45 +2,14 @@
 
 Counts are of the model's mathematics at the live context: what the
 request needs, not what a kernel happens to compute (no padding, no
-gathered cap, no masked tiles). A multiply-add is 2 operations.
+gathered cap, no masked tiles). A multiply-add is 2 operations. A
+model's own counts, ``decode_flops`` and ``prefill_flops``, are its
+family's (``families/``); here are the kernels' and the chip's.
 """
 
 from __future__ import annotations
 
-__all__ = ["dense_per_token", "decode_flops", "prefill_flops",
-           "flash_flops", "flash_bytes", "roofline_s", "load_peaks"]
-
-
-def dense_per_token(k: dict) -> float:
-    """Projections and MLP of every layer, per token (no attention
-    scores, no logits)."""
-    d, hd = k["d"], k["head_dim"]
-    proj = 2 * d * hd * (k["heads"] + 2 * k["kv_heads"]) \
-        + 2 * k["heads"] * hd * d
-    mlp = 3 * 2 * d * k["ff"]
-    return k["layers"] * (proj + mlp)
-
-
-def _attn_per_key(k: dict) -> float:
-    """Scores and weighted values, per (query, key) pair, all layers."""
-    return k["layers"] * 4 * k["heads"] * k["head_dim"]
-
-
-def _logits(k: dict) -> float:
-    return 2 * k["d"] * k["vocab"]
-
-
-def decode_flops(k: dict, ctx: int) -> float:
-    """One decoded token that attends over ``ctx`` keys (itself
-    included), with its logits."""
-    return dense_per_token(k) + _attn_per_key(k) * ctx + _logits(k)
-
-
-def prefill_flops(k: dict, n: int) -> float:
-    """A prompt of ``n`` tokens under a causal mask, with the logits of
-    its last token."""
-    return (n * dense_per_token(k) + _attn_per_key(k) * n * (n + 1) / 2
-            + _logits(k))
+__all__ = ["flash_flops", "flash_bytes", "roofline_s", "load_peaks"]
 
 
 def flash_flops(bh: int, s: int, head_dim: int) -> float:

@@ -1,6 +1,7 @@
 """The whole step's share of the chip's peak: the operations the served
 tokens need (prefills under a causal mask and each decoded token at its
-live context, flops.py) over the traced window times the bf16 peak."""
+live context, the family's count) over the traced window times the
+bf16 peak."""
 
 
 def read(run):

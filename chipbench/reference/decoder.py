@@ -9,6 +9,7 @@ and the output head. Every product runs in float32 at the highest
 precision. No kernel, cache or batch: one sequence, all its positions,
 one layer at a time, the layer's weights drawn from the seed as it is
 reached (:mod:`weights`), so the whole model never has to fit at once.
+The tensors are those ``families/dense.py`` names.
 
 ``fp8=True`` is the control: the same mathematics with every product's
 operands (weights, activations, queries, keys and values) rounded to
@@ -19,36 +20,16 @@ the bfloat16 the configurations serve in.
 from __future__ import annotations
 
 import functools
+from collections.abc import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 import weights as W
+from reference.common import CHUNK, HI, PAD, mm, to_fp8
 
 __all__ = ["Reference"]
-
-HI = jax.lax.Precision.HIGHEST
-
-#: padded sequence lengths are multiples of this; a caller that passes
-#: ``length`` (the cell's ``max_seq_len``) gets one compile for all
-PAD = 256
-
-#: queries per attention block
-CHUNK = 512
-
-
-def _fp8(x: jax.Array, axis: int) -> jax.Array:
-    """Round to float8 e4m3 under an absmax scale along ``axis``."""
-    s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / 448.0
-    s = jnp.where(s > 0, s, 1.0)
-    return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
-
-
-def _mm(x: jax.Array, w: jax.Array, fp8: bool) -> jax.Array:
-    if fp8:
-        x, w = _fp8(x, -1), _fp8(w, 0)
-    return jnp.dot(x, w, precision=HI)
 
 
 def _norm(x: jax.Array, p: dict, prefix: str, k: dict) -> jax.Array:
@@ -81,7 +62,7 @@ def _attention(q, kk, v, k: dict, fp8: bool) -> jax.Array:
     n, h, hd = q.shape
     g = k["kv_heads"]
     if fp8:
-        q, kk, v = _fp8(q, -1), _fp8(kk, -1), _fp8(v, -1)
+        q, kk, v = to_fp8(q, -1), to_fp8(kk, -1), to_fp8(v, -1)
     q = q.reshape(n, g, h // g, hd)
     outs = []
     for s in range(0, n, CHUNK):
@@ -98,25 +79,33 @@ def _layer(x: jax.Array, p: dict, k: dict, fp8: bool) -> jax.Array:
     n = x.shape[0]
     hd = k["head_dim"]
     h = _norm(x, p, "attn_norm", k)
-    q = _rope(_mm(h, p["q"], fp8).reshape(n, k["heads"], hd), k)
-    kk = _rope(_mm(h, p["k"], fp8).reshape(n, k["kv_heads"], hd), k)
-    v = _mm(h, p["v"], fp8).reshape(n, k["kv_heads"], hd)
-    x = x + _mm(_attention(q, kk, v, k, fp8), p["o"], fp8)
+    q = _rope(mm(h, p["q"], fp8).reshape(n, k["heads"], hd), k)
+    kk = _rope(mm(h, p["k"], fp8).reshape(n, k["kv_heads"], hd), k)
+    v = mm(h, p["v"], fp8).reshape(n, k["kv_heads"], hd)
+    x = x + mm(_attention(q, kk, v, k, fp8), p["o"], fp8)
     h = _norm(x, p, "mlp_norm", k)
-    return x + _mm(jax.nn.silu(_mm(h, p["gate"], fp8)) * _mm(h, p["up"], fp8),
-                   p["down"], fp8)
+    return x + mm(jax.nn.silu(mm(h, p["gate"], fp8)) * mm(h, p["up"], fp8),
+                  p["down"], fp8)
 
 
 class Reference:
-    """The reference model of one configuration and one seed."""
+    """The reference model of one configuration and one seed.
+
+    It draws the tensors its family names: the family's subclass sets
+    ``global_specs`` and ``layer_specs`` (``k -> {name: (shape, init)}``),
+    the specs it also lays out as the program's parameters."""
+
+    global_specs: Callable[[dict], dict]
+    layer_specs: Callable[[dict], dict]
 
     def __init__(self, k: dict, seed: int, dtype=jnp.bfloat16) -> None:
         self.k = k
         self.key = W.seed_key(seed)
-        g = W.global_specs(k)
+        g = self.global_specs(k)
+        per_layer = self.layer_specs(k)
 
         def tensor(key, name, layer=0):
-            shape, init = (g | W.layer_specs(k))[name]
+            shape, init = (g | per_layer)[name]
             return W.draw(key, name, shape, init, layer,
                           dtype).astype(jnp.float32)
 
@@ -127,14 +116,14 @@ class Reference:
         @functools.partial(jax.jit, static_argnames="fp8")
         def layer(key, x, l, fp8):
             p = {n: W.draw(key, n, s, i, l, dtype).astype(jnp.float32)
-                 for n, (s, i) in W.layer_specs(k).items()}
+                 for n, (s, i) in per_layer.items()}
             return _layer(x, p, k, fp8)
 
         @functools.partial(jax.jit, static_argnames="fp8")
         def head(key, x, fp8):
             p = {n: tensor(key, n) for n in g if n != "embed"}
             w = (tensor(key, "embed").T if k["tied"] else p["unembed"])
-            return _mm(_norm(x, p, "final_norm", k), w, fp8)
+            return mm(_norm(x, p, "final_norm", k), w, fp8)
 
         self._embed, self._layer, self._head = embed, layer, head
 
@@ -151,30 +140,3 @@ class Reference:
         for l in range(self.k["layers"]):
             x = self._layer(self.key, x, l, fp8)
         return self._head(self.key, x, fp8)
-
-
-@jax.jit
-def _gap(ref: jax.Array, tokens: jax.Array) -> jax.Array:
-    """How far each position's token lies below the reference's best."""
-    pick = jnp.take_along_axis(ref, tokens[:, None], axis=1)[:, 0]
-    return ref.max(axis=1) - pick
-
-
-def served_gaps(ref: Reference, prompt: np.ndarray, served: np.ndarray,
-                control: bool = False, length: int = 0) -> dict:
-    """The gap of every served token (and, with ``control``, of the token
-    the fp8 control puts first at the same position), as numpy arrays
-    over the served positions."""
-    seq = np.concatenate([prompt, served]).astype(np.int32)
-    inp, nxt = seq[:-1], seq[1:]
-    lo, hi = len(prompt) - 1, len(inp)
-    out = {}
-    if control:
-        pick = jnp.argmax(ref.logits(inp, fp8=True, length=length), axis=1)
-    r = ref.logits(inp, length=length)
-    pad = np.zeros(r.shape[0], np.int32)
-    pad[: len(nxt)] = nxt
-    out["served"] = np.asarray(_gap(r, jnp.asarray(pad)))[lo:hi]
-    if control:
-        out["control"] = np.asarray(_gap(r, pick.astype(jnp.int32)))[lo:hi]
-    return out

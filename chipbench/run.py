@@ -64,7 +64,6 @@ import devtrace  # noqa: E402
 import flops  # noqa: E402
 import spec  # noqa: E402
 import traffic  # noqa: E402
-import weights  # noqa: E402
 from loop import Server  # noqa: E402
 
 TRACE_DIR = spec.BENCH_DIR / ".cache" / "trace"
@@ -157,15 +156,16 @@ class Session:
         from repro.configs import build_model
 
         self.cell = cell
-        self.k = spec.dims(cell.config)
-        self.arch = spec.program_config(cell.config)
+        self.k = cell.k
+        self.arch = cell.family.program_config(cell.config)
         self.model = build_model(self.arch)
         self.dtype = jnp.dtype(cell.config["dtype"])
         self.kv_dtype = jnp.dtype(cell.config["kv_dtype"])
         self.tuner = load_tuner() if tuner else None
 
     def params(self, seed: int):
-        return weights.program_params(self.model, self.k, seed, self.dtype)
+        return self.cell.family.program_params(self.model, self.k, seed,
+                                               self.dtype)
 
     def scheduler(self, params):
         from repro.serve.scheduler import ContinuousBatchingScheduler
@@ -224,10 +224,18 @@ class RunView:
     def prefill_lengths(self) -> list[int]:
         return [n for s in self.traced_steps() for n in s.prefills]
 
+    def prefill_flops(self) -> float:
+        """Operations of the prompts admitted in the traced steps."""
+        fam = self.cell.family
+        return sum(fam.prefill_flops(self.k, n)
+                   for n in self.prefill_lengths())
+
     def window_flops(self) -> float:
-        k = self.k
-        return sum(sum(flops.prefill_flops(k, n) for n in s.prefills)
-                   + sum(flops.decode_flops(k, c) for c in s.decode_ctx)
+        """Operations of the traced steps: each admitted prompt and each
+        decoded token at its live context, counted by the family."""
+        fam, k = self.cell.family, self.k
+        return sum(sum(fam.prefill_flops(k, n) for n in s.prefills)
+                   + sum(fam.decode_flops(k, c) for c in s.decode_ctx)
                    for s in self.traced_steps())
 
 
@@ -358,7 +366,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     del server, sched, params
     gc.collect()
     t_ref = time.perf_counter()
-    cmp = checks.compare(k, seed, lg, picked, control=control,
+    cmp = checks.compare(cell, seed, lg, picked, control=control,
                          length=t["max_seq_len"])
     log(f"reference: {cmp['requests']} requests, {cmp['tokens']} served "
         f"tokens compared in {time.perf_counter() - t_ref:.6f} s")

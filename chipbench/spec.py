@@ -6,6 +6,13 @@ of its own under this directory: ``configs/<file>`` (named in
 (the limits of the comparison that decides ``correct``) and
 ``metrics/<metric>.py`` (one reader per per-layer metric). A new cell,
 mix or metric is new files plus new entries; nothing here changes.
+
+A configuration names its architecture's family (``"family"``), which
+``families/<family>.py`` defines: ``dims``, ``program_config``,
+``program_params``, ``Reference``, ``decode_flops``, ``prefill_flops``
+and ``smoke`` (``families/__init__.py`` says what each is). So a
+configuration of a new architecture is also new files, a family module
+and its reference under ``reference/``, plus entries.
 """
 
 from __future__ import annotations
@@ -13,6 +20,9 @@ from __future__ import annotations
 import dataclasses
 import json
 from pathlib import Path
+from types import ModuleType
+
+import families
 
 #: this directory, and the checkout that holds it
 BENCH_DIR = Path(__file__).resolve().parent
@@ -30,10 +40,16 @@ class Cell:
     name: str
     chips: int
     config: dict            # the configuration as run
+    family: ModuleType      # families/<config["family"]>.py
     traffic: dict           # the traffic mix's parameters
     limits: dict            # {"logit_gap": ..., ...} for ``correct``
     end_to_end: list        # BENCHMARK.json metric entries this cell reports
     per_layer: list
+
+    @property
+    def k(self) -> dict:
+        """The sizes the harness computes with (the family's ``dims``)."""
+        return self.family.dims(self.config)
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -46,7 +62,10 @@ def _reported_by(metric: dict, workload: str) -> bool:
 
 
 def load_cell(workload: str, root: Path = ROOT) -> Cell:
+    """A workload of the checkout at ``root``, with its files found under
+    that checkout's copy of this directory."""
     bench = load_benchmark(root)
+    here = root / BENCH_DIR.relative_to(ROOT)
     by_name = {w["name"]: w for w in bench["workloads"]}
     if workload not in by_name:
         raise SystemExit(f"unknown workload {workload!r}; "
@@ -55,59 +74,15 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     cfg_entry = next(c for c in bench["configs"] if c["name"] == w["config"])
     with open(root / cfg_entry["file"]) as f:
         config = json.load(f)
-    with open(BENCH_DIR / "traffic" / f"{w['traffic']}.json") as f:
+    with open(here / "traffic" / f"{w['traffic']}.json") as f:
         traffic = json.load(f)
-    with open(BENCH_DIR / "limits" / f"{workload}.json") as f:
+    with open(here / "limits" / f"{workload}.json") as f:
         limits = json.load(f)
     return Cell(
         name=workload, chips=int(w["chips"]), config=config,
+        family=families.load(config.get("family"), here / "families"),
         traffic=traffic, limits=limits,
         end_to_end=[m for m in bench["end_to_end"]
                     if _reported_by(m, workload)],
         per_layer=[m for m in bench["per_layer"]
                    if _reported_by(m, workload)])
-
-
-def dims(config: dict) -> dict:
-    """The sizes the harness computes with, from a configuration file."""
-    d = int(config["hidden_size"])
-    h = int(config["num_attention_heads"])
-    return {
-        "d": d,
-        "ff": int(config["intermediate_size"]),
-        "heads": h,
-        "kv_heads": int(config["num_key_value_heads"]),
-        "head_dim": int(config.get("head_dim") or d // h),
-        "layers": int(config["num_hidden_layers"]),
-        "vocab": int(config["vocab_size"]),
-        "rotary": float(config.get("partial_rotary_factor", 1.0)),
-        "rope_theta": float(config["rope_theta"]),
-        "norm": "layernorm" if "layer_norm_eps" in config else "rmsnorm",
-        "norm_eps": float(config.get("layer_norm_eps",
-                                     config.get("rms_norm_eps"))),
-        "tied": bool(config.get("tie_word_embeddings", False)),
-    }
-
-
-def program_config(config: dict):
-    """The program's ``ArchConfig`` for a configuration file: the
-    program's own entry for ``program_arch`` with the file's sizes, and a
-    check that everything else the program fixes agrees with the file."""
-    import dataclasses as dc
-
-    from repro.configs import get_config
-
-    k = dims(config)
-    arch = dc.replace(
-        get_config(config["program_arch"]), n_layers=k["layers"],
-        d_model=k["d"], n_heads=k["heads"], n_kv_heads=k["kv_heads"],
-        d_ff=k["ff"], vocab=k["vocab"], head_dim=None)
-    want = {"resolved_head_dim": k["head_dim"],
-            "rope_fraction": k["rotary"], "norm_kind": k["norm"],
-            "tie_embeddings": k["tied"], "mlp_kind": "swiglu",
-            "attn_kind": "gqa", "window": None, "qk_norm": False}
-    got = {key: getattr(arch, key) for key in want}
-    if got != want:
-        raise ValueError(f"{config['name']}: the program's configuration "
-                         f"{got} departs from the file's {want}")
-    return arch

@@ -17,14 +17,13 @@ from conftest import smoke_cell
 
 PEAKS = {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e11}
 
-CASES = [("stablelm-1.6b", "chat", "stablelm-1.6b.chat"),
-         ("granite-8b", "code", "granite-8b.code")]
+CASES = ["stablelm-1.6b.chat", "granite-8b.code"]
 
 
-@pytest.mark.parametrize("case", CASES, ids=lambda c: c[1])
+@pytest.mark.parametrize("case", CASES, ids=lambda w: w.rsplit(".", 1)[1])
 def test_the_fp8_control_fails_the_limit(monkeypatch, case):
     monkeypatch.setattr(run, "_peaks", lambda dev: PEAKS)
-    cell = smoke_cell(*case, hidden_size=256, intermediate_size=512,
+    cell = smoke_cell(case, hidden_size=256, intermediate_size=512,
                       num_hidden_layers=4, vocab_size=2048)
     cell.traffic["check"] = {"min_tokens": 200, "max_requests": 64}
     res = run.run_cell(cell, 5, 3.0, False,

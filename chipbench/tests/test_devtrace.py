@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import pytest
 
 import devtrace
+import families
 import flops
 import run
 from devtrace import Event, Trace
@@ -82,13 +83,14 @@ def _view(trace, prefills, decode_ctx):
 
     class Cell:
         traffic = {"slots": 4}
+        family = families.load("dense")
     peak = {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e11}
     return run.RunView(Cell, k, trace, lg, peak, {"compiles_in_window": 0})
 
 
 def test_readers_on_the_known_trace():
     v = _view(_trace(), [100], [100, 200])
-    k = v.k
+    k, dense = v.k, v.cell.family
     assert run.read_metric("decode_step_ms", v) == pytest.approx(20.0)
     # busy 52 ms on device 0 and 50 ms on device 1
     assert run.read_metric("idle_share", v) == pytest.approx(49.0)
@@ -97,12 +99,12 @@ def test_readers_on_the_known_trace():
                                  flops.flash_bytes(4, 100, 16, 2), v.peak)
     assert run.read_metric("flash_roofline", v) == pytest.approx(
         100 * least / 0.010)
-    want = (flops.prefill_flops(k, 100) + flops.decode_flops(k, 100)
-            + flops.decode_flops(k, 200))
+    want = (dense.prefill_flops(k, 100) + dense.decode_flops(k, 100)
+            + dense.decode_flops(k, 200))
     assert run.read_metric("mfu", v) == pytest.approx(
         100 * want / (0.1 * 1e12))
     assert run.read_metric("prefill_mfu.chat", v) == pytest.approx(
-        100 * flops.prefill_flops(k, 100) / (0.030 * 1e12))
+        100 * dense.prefill_flops(k, 100) / (0.030 * 1e12))
     # a variant without a reader of its own is read by its base's
     for name in ("decode_step_ms", "idle_share", "flash_roofline"):
         assert run.read_metric(f"{name}.chat", v) == run.read_metric(name, v)

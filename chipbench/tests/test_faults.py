@@ -60,16 +60,15 @@ class Faulty(run.Session):
         return sched
 
 
-CASES = [("stablelm-1.6b", "chat", "stablelm-1.6b.chat"),
-         ("granite-8b", "code", "granite-8b.code")]
+CASES = ["stablelm-1.6b.chat", "granite-8b.code"]
 
 
 @pytest.mark.parametrize("fault", [None, _token, _state, _half],
                          ids=["sound", "token", "state", "half"])
-@pytest.mark.parametrize("case", CASES, ids=lambda c: c[1])
+@pytest.mark.parametrize("case", CASES, ids=lambda w: w.rsplit(".", 1)[1])
 def test_a_broken_path_is_not_correct(monkeypatch, case, fault):
     monkeypatch.setattr(run, "_peaks", lambda dev: PEAKS)
-    cell = smoke_cell(*case)
+    cell = smoke_cell(case)
     # every slot busy, and enough of the served requests compared that
     # some of them decoded in each half of the slots
     if cell.traffic["loop"] == "open":

@@ -1,6 +1,6 @@
-"""``flops.py`` against the program's own analytic arithmetic
-(``repro.roofline.analytic``) on both configurations, and the peaks
-table."""
+"""The dense family's operation counts against the program's own
+analytic arithmetic (``repro.roofline.analytic``) on both
+configurations; ``flops.py``'s kernel counts and the peaks table."""
 
 import pytest
 
@@ -13,33 +13,33 @@ from repro.roofline import analytic
 @pytest.fixture(params=["stablelm-1.6b.chat", "granite-8b.code"])
 def both(request):
     cell = spec.load_cell(request.param)
-    return spec.dims(cell.config), spec.program_config(cell.config)
+    return cell.k, cell.family.program_config(cell.config), cell.family
 
 
 @pytest.mark.parametrize("ctx", [1, 384, 4096])
 def test_decode_token_matches_analytic(both, ctx):
-    k, arch = both
+    k, arch, fam = both
     want = analytic.fwd_flops(arch, ShapeSpec("d", ctx, 1, "decode"))
-    assert flops.decode_flops(k, ctx) == pytest.approx(want, rel=1e-12)
+    assert fam.decode_flops(k, ctx) == pytest.approx(want, rel=1e-12)
 
 
 @pytest.mark.parametrize("n", [64, 1024, 4032])
 def test_prefill_is_analytic_with_an_exact_triangle_and_one_logit_row(
         both, n):
-    k, arch = both
+    k, arch, fam = both
     # analytic counts every token's logits and each query at the mean
     # context n/2; the served prefill computes one row of logits and the
     # causal triangle holds n(n+1)/2 pairs, not n*n/2
     want = analytic.fwd_flops(arch, ShapeSpec("p", n, 1, "prefill"))
     want -= (n - 1) * 2 * k["d"] * k["vocab"]
     want += k["layers"] * 4 * k["heads"] * k["head_dim"] * n / 2
-    assert flops.prefill_flops(k, n) == pytest.approx(want, rel=1e-12)
+    assert fam.prefill_flops(k, n) == pytest.approx(want, rel=1e-12)
 
 
 def test_dense_part_is_twice_the_layer_parameters(both):
-    k, arch = both
+    k, arch, fam = both
     emb = arch.vocab * arch.d_model * (1 if arch.tie_embeddings else 2)
-    assert flops.dense_per_token(k) == 2 * (arch.param_count() - emb)
+    assert fam.dense_per_token(k) == 2 * (arch.param_count() - emb)
 
 
 def test_flash_counts_the_causal_triangle():

@@ -7,27 +7,26 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import spec
 import weights
 from conftest import smoke_cell
-from reference.decoder import Reference, served_gaps
+from reference.common import served_gaps
 from repro.configs import build_model
 from repro.serve.scheduler import ContinuousBatchingScheduler
 from repro.train.step import make_ctx
 
-CELLS = [("stablelm-1.6b", "chat", "stablelm-1.6b.chat"),
-         ("granite-8b", "code", "granite-8b.code")]
+CELLS = ["stablelm-1.6b.chat", "granite-8b.code"]
 
 
-@pytest.fixture(scope="module", params=CELLS, ids=lambda c: c[0])
+@pytest.fixture(scope="module", params=CELLS,
+                ids=lambda w: w.rsplit(".", 1)[0])
 def served(request):
     """Float32 weights from seed 3 in both; four ragged requests through
     the scheduler (2 slots, pages of 4, so slots and pages are reused)."""
-    cell = smoke_cell(*request.param)
-    k = spec.dims(cell.config)
-    arch = spec.program_config(cell.config)
+    cell = smoke_cell(request.param)
+    k, fam = cell.k, cell.family
+    arch = fam.program_config(cell.config)
     model = build_model(arch)
-    params = weights.program_params(model, k, 3, jnp.float32)
+    params = fam.program_params(model, k, 3, jnp.float32)
     sched = ContinuousBatchingScheduler(
         model, arch, params, slots=2, n_pages=24, page_size=4,
         max_seq_len=40, dtype=jnp.float32)
@@ -37,7 +36,7 @@ def served(request):
         toks = rng.integers(0, k["vocab"], n).astype(np.int32)
         reqs[sched.submit(toks.tolist(), new)] = toks
     fin = sched.run_until_drained()
-    ref = Reference(k, 3, dtype=jnp.float32)
+    ref = fam.Reference(k, 3, dtype=jnp.float32)
     return k, model, params, ref, reqs, fin
 
 
@@ -70,11 +69,12 @@ def test_the_reference_sees_a_wrong_token(served):
 
 
 def test_vmapped_layers_draw_what_one_layer_draws():
-    k = spec.dims(smoke_cell(*CELLS[1]).config)
+    cell = smoke_cell(CELLS[1])
+    k, draw_layer = cell.k, cell.family.draw_layer
     key = weights.seed_key(2**40 + 5)
-    stacked = jax.vmap(lambda l: weights.draw_layer(
+    stacked = jax.vmap(lambda l: draw_layer(
         key, k, l, jnp.bfloat16))(jnp.arange(k["layers"]))
-    one = weights.draw_layer(key, k, 1, jnp.bfloat16)
+    one = draw_layer(key, k, 1, jnp.bfloat16)
     for name in one:
         np.testing.assert_array_equal(np.asarray(stacked[name][1]),
                                       np.asarray(one[name]))

@@ -117,7 +117,7 @@ def test_offline_window_opens_when_the_pool_is_full():
     import run
     from conftest import smoke_cell
 
-    cell = smoke_cell("granite-8b", "code", "granite-8b.code")
+    cell = smoke_cell("granite-8b.code")
     cell.traffic["pool_pages"] = 16
     res = run.run_cell(cell, BIG, 0.5, False,
                        session=run.Session(cell, tuner=False), t_start=0.0,
