@@ -4,14 +4,17 @@ The continuous-batching decode step attends each slot's one new query
 token to the slot's cached keys and values. They live in a shared page
 pool ``(n_pages, page_size, n_kv_heads * head_dim)`` (one token's KV
 heads side by side in one row, :mod:`repro.serve.kv_cache`), addressed
-through a per-slot page table. The kernel reads only what is live:
+through a per-slot page table. The kernel takes a stack of such pools,
+one per layer of the model's layer scan, and the index of the layer to
+read, so the scan hands it the stack it carries and no layer's pool is
+ever sliced out whole. The kernel reads only what is live:
 
-* the per-slot lengths and the page table are scalar-prefetched; for
-  slot ``b`` the kernel copies the pages ``0 .. ceil(len[b] / page) - 1``
-  of its table row straight from the pool in HBM, one whole page (every
-  KV head) per DMA, and nothing past them: holes and pages beyond the
-  live prefix are never read, and an inactive slot (``len == 0``) reads
-  nothing;
+* the per-slot lengths, the page table and the layer are
+  scalar-prefetched; for slot ``b`` the kernel copies the pages
+  ``0 .. ceil(len[b] / page) - 1`` of its table row straight from the
+  layer's pool in HBM, one whole page (every KV head) per DMA, and
+  nothing past them: holes and pages beyond the live prefix are never
+  read, and an inactive slot (``len == 0``) reads nothing;
 * pages are copied in blocks of :func:`_pages_per_block`,
   double-buffered: while one block is reduced the next is in flight,
   also across slots (the grid runs the slots in order, and the last
@@ -62,6 +65,7 @@ def _pages_per_block(page_size: int, row_width: int, itemsize: int,
 
 
 def _paged_decode_kernel(lens_ref, table_ref, nxt_ref,     # scalar prefetch
+                         layer_ref,
                          q_ref, diag_ref, take_ref,        # VMEM inputs
                          k_hbm, v_hbm,                     # pools, in HBM
                          o_ref,                            # output
@@ -70,7 +74,8 @@ def _paged_decode_kernel(lens_ref, table_ref, nxt_ref,     # scalar prefetch
                          n_slots: int, table_pages: int, ppb: int,
                          sm_scale: float):
     b = pl.program_id(0)
-    n_pages, page, width = k_hbm.shape
+    _, n_pages, page, width = k_hbm.shape
+    layer = layer_ref[0]
     bk = ppb * page                                  # tokens per block
 
     def copies(slot, blk, buf):
@@ -83,10 +88,12 @@ def _paged_decode_kernel(lens_ref, table_ref, nxt_ref,     # scalar prefetch
             def make(i=i, j=j):
                 pid = jnp.clip(table_ref[slot * table_pages + j], 0,
                                n_pages - 1)
-                return (pltpu.make_async_copy(
-                            k_hbm.at[pid], kbuf.at[buf, i], sems.at[0, buf]),
-                        pltpu.make_async_copy(
-                            v_hbm.at[pid], vbuf.at[buf, i], sems.at[1, buf]))
+                return (pltpu.make_async_copy(k_hbm.at[layer, pid],
+                                              kbuf.at[buf, i],
+                                              sems.at[0, buf]),
+                        pltpu.make_async_copy(v_hbm.at[layer, pid],
+                                              vbuf.at[buf, i],
+                                              sems.at[1, buf]))
             out.append((j < live, make))
         return out
 
@@ -166,20 +173,23 @@ def _paged_decode_kernel(lens_ref, table_ref, nxt_ref,     # scalar prefetch
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
                                   v_pages: jax.Array, lengths: jax.Array,
-                                  page_table: jax.Array, *,
+                                  page_table: jax.Array,
+                                  layer: int | jax.Array, *,
                                   interpret: bool = False) -> jax.Array:
     """softmax(q kᵀ / sqrt(Dh)) v for one query token per slot, over the
-    slot's live pages.
+    slot's live pages in the pools of ``layer``.
 
-    q: ``(B, H, Dh)``; k_pages / v_pages: ``(n_pages, page, Hkv * Dh)``
-    with ``H`` a multiple of ``Hkv``; lengths: ``(B,)`` live tokens per
-    slot (0 = inactive slot, whose output is zeros); page_table:
-    ``(B, T)`` physical page of each logical page (entries past
-    ``ceil(len / page)`` are never read). Returns ``(B, H, Dh)`` in q's
-    dtype.
+    q: ``(B, H, Dh)``; k_pages / v_pages: ``(L, n_pages, page, Hkv *
+    Dh)``, a stack of per-layer pools (a lone pool is passed as
+    ``pool[None]``), with ``H`` a multiple of ``Hkv``; lengths: ``(B,)``
+    live tokens per slot (0 = inactive slot, whose output is zeros);
+    page_table: ``(B, T)`` physical page of each logical page (entries
+    past ``ceil(len / page)`` are never read); layer: the index into
+    ``L`` to read, an int or an int32 scalar. Returns ``(B, H, Dh)`` in
+    q's dtype.
     """
     b, heads, dh = q.shape
-    n_pages, page, width = k_pages.shape
+    _, n_pages, page, width = k_pages.shape
     if k_pages.shape != v_pages.shape or width % dh \
             or heads % (width // dh):
         raise ValueError(f"bad paged attention shapes q {q.shape}, "
@@ -206,7 +216,7 @@ def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
         _paged_decode_kernel, n_slots=b, table_pages=table_pages, ppb=ppb,
         sm_scale=float(dh) ** -0.5)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, heads, width), lambda i, *_: (i, 0, 0)),
@@ -233,6 +243,7 @@ def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(lengths, page_table.reshape(-1).astype(jnp.int32), nxt, q_diag,
+    )(lengths, page_table.reshape(-1).astype(jnp.int32), nxt,
+      jnp.asarray(layer, jnp.int32).reshape(1), q_diag,
       jnp.asarray(diag, jnp.float32), jnp.asarray(take, jnp.float32),
       k_pages, v_pages)

@@ -416,14 +416,19 @@ def attention_decode(p: dict, x: jax.Array, s: AttnSpec, cache: KVCache,
 
 def attention_decode_paged(p: dict, x: jax.Array, s: AttnSpec, pool,
                            page_table: jax.Array, pos: jax.Array,
-                           tuner=None, backend: str = "auto"):
+                           tuner=None, backend: str = "auto",
+                           layer: jax.Array | None = None):
     """One-token decode against a paged KV pool (continuous batching).
 
     x (B, 1, D); ``pos`` is (B,) int32 — every sequence in the batch
     sits at its own position (ragged admission), with -1 marking an
     inactive batch slot; ``page_table`` (B, P) int32 maps each
     sequence's logical pages to physical pages of ``pool``
-    (:class:`repro.serve.kv_cache.PagedKV`), -1 marking holes.
+    (:class:`repro.serve.kv_cache.PagedKV`), -1 marking holes.  With
+    ``layer`` (a scalar index), ``pool`` holds the stacked pools of
+    every layer of a scan, ``(L, n_pages, page, Hkv * Dh)``: this layer
+    writes its rows into ``pool[layer]`` and reads from there, and the
+    whole updated stack is returned.
 
     Where ``backend`` resolves to ``"pallas"`` the attention core is
     :func:`repro.kernels.paged_attention.paged_decode_attention_pallas`:
@@ -455,17 +460,20 @@ def attention_decode_paged(p: dict, x: jax.Array, s: AttnSpec, pool,
     active = pos >= 0
     pool = type(pool)(
         append_token(pool.k, page_table, pos, k_new[:, 0].reshape(b, -1),
-                     active),
+                     active, layer),
         append_token(pool.v, page_table, pos, v_new[:, 0].reshape(b, -1),
-                     active))
+                     active, layer))
     if ops.resolve_backend(backend) == "pallas":
+        # the kernel takes a stack of pools: one pool is a stack of one
+        k, v, at = ((pool.k[None], pool.v[None], 0) if layer is None
+                    else (pool.k, pool.v, layer))
         out = paged_decode_attention_pallas(
-            q[:, 0], pool.k, pool.v, jnp.where(active, pos + 1, 0),
-            page_table, interpret=ops.resolve_interpret())
+            q[:, 0], k, v, jnp.where(active, pos + 1, 0), page_table, at,
+            interpret=ops.resolve_interpret())
     else:
         kv = (b, cap, s.n_kv_heads, s.head_dim)
-        k = gather_pages(pool.k, page_table).reshape(kv)
-        v = gather_pages(pool.v, page_table).reshape(kv)
+        k = gather_pages(pool.k, page_table, layer).reshape(kv)
+        v = gather_pages(pool.v, page_table, layer).reshape(kv)
         kk = _repeat_kv(k, s.n_heads)
         vv = _repeat_kv(v, s.n_heads)
         scores = jnp.einsum("bohd,bkhd->bhk", q.astype(jnp.float32),

@@ -214,13 +214,16 @@ def mla_decode(p: dict, x: jax.Array, s: MLASpec, cache: MLACache,
 
 
 def mla_decode_paged(p: dict, x: jax.Array, s: MLASpec, pool,
-                     page_table: jax.Array, pos: jax.Array, tuner=None):
+                     page_table: jax.Array, pos: jax.Array, tuner=None,
+                     layer: jax.Array | None = None):
     """One-token decode against a paged latent pool (continuous batching).
 
     The paged twin of :func:`mla_decode`: ``pos`` is (B,) int32 per-
     sequence positions (-1 = inactive slot), ``page_table`` (B, P)
     maps logical pages to physical pages of the
-    :class:`repro.serve.kv_cache.PagedLatent` pool.  Same absorbed-
+    :class:`repro.serve.kv_cache.PagedLatent` pool; with ``layer``,
+    ``pool`` stacks every scanned layer's pools and this layer reads and
+    writes ``pool[layer]``, returning the whole stack.  Same absorbed-
     projection math on the page-gathered latent view, bitwise equal
     per sequence to the contiguous path; the latent cache update keeps
     its TRSM-site recorder tag.
@@ -234,11 +237,12 @@ def mla_decode_paged(p: dict, x: jax.Array, s: MLASpec, pool,
                 tuner, routine="trsm", site="mla.cache_update")
     active = pos >= 0
     pool = type(pool)(
-        append_token(pool.c_kv, page_table, pos, c_kv_new[:, 0], active),
+        append_token(pool.c_kv, page_table, pos, c_kv_new[:, 0], active,
+                     layer),
         append_token(pool.k_rope, page_table, pos, k_rope_new[:, 0],
-                     active))
-    c_kv = gather_pages(pool.c_kv, page_table)       # (B, cap, R_kv)
-    k_rope = gather_pages(pool.k_rope, page_table)   # (B, cap, R_rope)
+                     active, layer))
+    c_kv = gather_pages(pool.c_kv, page_table, layer)      # (B, cap, R_kv)
+    k_rope = gather_pages(pool.k_rope, page_table, layer)  # (B, cap, R_rope)
     q_nope = q[..., : s.qk_nope_dim]       # (B, 1, H, nope)
     q_rope = q[..., s.qk_nope_dim:]        # (B, 1, H, rope)
     wk_b = p["wk_b"].reshape(s.kv_lora_rank, s.n_heads, s.qk_nope_dim)

@@ -336,23 +336,26 @@ def _init_layer_paged(cfg: ArchConfig, spec: LayerSpec, n_pages: int,
 
 def _apply_layer_decode(p: dict, x: jax.Array, cache: Any,
                         pos: jax.Array, cfg: ArchConfig, spec: LayerSpec,
-                        ctx: Ctx, page_table: jax.Array | None = None
+                        ctx: Ctx, page_table: jax.Array | None = None,
+                        layer: jax.Array | None = None
                         ) -> tuple[jax.Array, Any]:
     """``page_table`` switches the attention mixers onto the paged read
     path (cache leaves are PagedKV/PagedLatent pools, ``pos`` is (B,)
-    per-sequence positions) — the continuous-batching decode."""
+    per-sequence positions) — the continuous-batching decode.  With
+    ``layer`` the pools are the layer scan's stacks and this layer
+    works on ``[layer]`` of them."""
     h = L.apply_norm(p["ln1"], x, cfg.norm_kind)
     if spec.kind in ("attn", "local"):
         if page_table is not None:
             if cfg.attn_kind == "mla":
                 mix, cache = MLA.mla_decode_paged(
                     p["mixer"], h, _mla_spec(cfg), cache, page_table,
-                    pos, tuner=ctx.tuner)
+                    pos, tuner=ctx.tuner, layer=layer)
             else:
                 mix, cache = L.attention_decode_paged(
                     p["mixer"], h, _attn_spec(cfg, spec.kind), cache,
                     page_table, pos, tuner=ctx.tuner,
-                    backend=ctx.backend)
+                    backend=ctx.backend, layer=layer)
         elif cfg.attn_kind == "mla":
             mix, cache = MLA.mla_decode(p["mixer"], h, _mla_spec(cfg),
                                         cache, pos, tuner=ctx.tuner)
@@ -591,6 +594,11 @@ class LM:
         With ``page_table`` (B, P) the cache tree holds page pools and
         ``pos`` is (B,) per-sequence positions (-1 = inactive slot) —
         the continuous-batching paged decode (repro.serve.scheduler).
+        There the scanned layers' stacked pools are loop-carried state:
+        each layer writes its B new rows into the stack in place and
+        reads its live pages from it by layer index, so a step moves
+        neither a layer's whole pool nor the stack.  The contiguous
+        caches (no ``page_table``) are scanned per layer as ``xs``.
         """
         cfg = self.cfg
         x = self._embed(params, token)
@@ -602,7 +610,7 @@ class LM:
             new_prefix.append(c2)
 
         new_scan = cache["scan"]
-        if self.repeats:
+        if self.repeats and page_table is None:
             unit = self.unit
 
             def body(h, pc):
@@ -611,12 +619,29 @@ class LM:
                 for i, s in enumerate(unit):
                     h, c2 = _apply_layer_decode(
                         layer_params[i], h, layer_cache[i], pos, cfg, s,
-                        ctx, page_table)
+                        ctx)
                     new_caches.append(c2)
                 return h, new_caches
 
             x, new_scan = jax.lax.scan(
                 body, x, (params["scan"], cache["scan"]))
+        elif self.repeats:
+            unit = self.unit
+
+            def paged_body(carry, xs):
+                h, pools = carry
+                layer_params, layer = xs
+                new_pools = []
+                for i, s in enumerate(unit):
+                    h, c2 = _apply_layer_decode(
+                        layer_params[i], h, pools[i], pos, cfg, s, ctx,
+                        page_table, layer)
+                    new_pools.append(c2)
+                return (h, new_pools), None
+
+            (x, new_scan), _ = jax.lax.scan(
+                paged_body, (x, cache["scan"]),
+                (params["scan"], jnp.arange(self.repeats)))
 
         new_suffix = []
         for p, s, c in zip(params["suffix"], self.suffix, cache["suffix"]):

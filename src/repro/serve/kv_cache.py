@@ -224,9 +224,15 @@ def init_paged_latent(n_pages: int, page_size: int, kv_lora_rank: int,
 # Jittable device primitives
 # ---------------------------------------------------------------------------
 
-def gather_pages(pages: jax.Array, table: jax.Array) -> jax.Array:
+def gather_pages(pages: jax.Array, table: jax.Array,
+                 layer: jax.Array | None = None) -> jax.Array:
     """Page-table gather: ``(P, page, ...)`` pool + ``(B, T)`` table ->
     a contiguous per-sequence ``(B, T*page, ...)`` view.
+
+    With ``layer``, ``pages`` is a stack of per-layer pools
+    ``(L, P, page, ...)`` and the view is read from ``pages[layer]`` in
+    the same single gather, so the layer's pool is never sliced out
+    whole.
 
     Holes (:data:`HOLE`) clamp to page 0; whatever that page holds
     lands at token slots at/after the sequence's allocated prefix,
@@ -236,30 +242,39 @@ def gather_pages(pages: jax.Array, table: jax.Array) -> jax.Array:
     kernel agrees with both to f32 rounding only).  The gather reads
     the whole capped span of every slot, live or not."""
     b, t = table.shape
-    page = pages.shape[1]
-    gathered = jnp.take(pages, jnp.clip(table, 0, pages.shape[0] - 1),
-                        axis=0)
-    return gathered.reshape((b, t * page) + pages.shape[2:])
+    pool_shape = pages.shape if layer is None else pages.shape[1:]
+    ids = jnp.clip(table, 0, pool_shape[0] - 1)
+    gathered = (jnp.take(pages, ids, axis=0) if layer is None
+                else pages[layer, ids])
+    return gathered.reshape((b, t * pool_shape[1]) + pool_shape[2:])
 
 
 def append_token(pages: jax.Array, table: jax.Array, pos: jax.Array,
-                 new: jax.Array, active: jax.Array) -> jax.Array:
+                 new: jax.Array, active: jax.Array,
+                 layer: jax.Array | None = None) -> jax.Array:
     """Write one token per sequence: ``new[b]`` lands at physical slot
     ``(table[b, pos[b] // page], pos[b] % page)``.
+
+    With ``layer``, ``pages`` is a stack of per-layer pools
+    ``(L, P, page, ...)`` and the rows land in ``pages[layer]``: one
+    scatter into the stack, which XLA updates in place when the stack
+    is loop-carried state.
 
     Inactive slots (and holes) are routed to page id ``n_pages`` —
     out of bounds, so the scatter drops them (``mode="drop"``) instead
     of corrupting page 0.  Live sequences own disjoint pages, so the
     per-``b`` scatter indices never collide.
     """
-    n_pages, page = pages.shape[:2]
+    n_pages, page = (pages.shape if layer is None else pages.shape[1:])[:2]
     cap = table.shape[1] * page
     idx = jnp.clip(pos, 0, cap - 1)
     page_ix = jnp.take_along_axis(table, (idx // page)[:, None],
                                   axis=1)[:, 0]
     ok = active & (page_ix >= 0)
     page_ix = jnp.where(ok, page_ix, n_pages)
-    return pages.at[page_ix, idx % page].set(new, mode="drop")
+    if layer is None:
+        return pages.at[page_ix, idx % page].set(new, mode="drop")
+    return pages.at[layer, page_ix, idx % page].set(new, mode="drop")
 
 
 def seed_pages(pages: jax.Array, page_ids: jax.Array,

@@ -4,7 +4,9 @@
 The kernel reads only each slot's live pages and sums the softmax block
 by block; the XLA path gathers the whole capped span. They compute the
 same f32 arithmetic in another order, so outputs agree to the dtype's
-rounding, and the cache update (shared code) is bitwise equal.
+rounding, and the cache update (shared code) is bitwise equal. Both
+paths also work on one layer of a stack of layers' pools, as the layer
+scan of the serving decode step hands them.
 """
 
 import jax
@@ -23,6 +25,8 @@ PAGE = 16
 TABLE_PAGES = 8                      # cap = 128 token slots
 CAP = PAGE * TABLE_PAGES
 N_PAGES = 40
+#: layers in a stacked pool
+STACK = 3
 
 #: (heads, KV heads, head width): MHA with 64-wide heads (stablelm's
 #: shape) and GQA 4:1 with 128-wide heads (granite's)
@@ -61,24 +65,61 @@ def _layer(shape, dtype, lengths, seed=0):
     return p, x, s, pool, jnp.asarray(table), jnp.asarray(pos)
 
 
-_CASES = ([(shape, jnp.float32, n) for shape in SHAPES
+def _stack(pool, layer, seed=1):
+    """``STACK`` layers' pools of random rows with ``pool`` at
+    ``layer``."""
+    rng = np.random.default_rng(seed)
+
+    def stack(a):
+        rows = [np.asarray(rng.normal(size=a.shape), a.dtype)
+                for _ in range(STACK)]
+        rows[layer] = np.asarray(a)
+        return jnp.asarray(np.stack(rows))
+    return jax.tree.map(stack, pool)
+
+
+_CASES = ([(shape, jnp.float32, n, None) for shape in SHAPES
            for n in (1, 15, 16, 17, CAP)]
-          + [(shape, jnp.bfloat16, n) for shape in SHAPES
-             for n in (17, CAP)])
+          + [(shape, jnp.bfloat16, n, None) for shape in SHAPES
+             for n in (17, CAP)]
+          + [(shape, jnp.float32, 17, layer) for shape in SHAPES
+             for layer in (0, STACK - 1)])
 
 
 @pytest.mark.parametrize(
-    "shape,dtype,length", _CASES,
-    ids=[f"{s}-{jnp.dtype(d).name}-len{n}" for s, d, n in _CASES])
-def test_kernel_matches_xla_path(shape, dtype, length):
+    "shape,dtype,length,layer", _CASES,
+    ids=[f"{s}-{jnp.dtype(d).name}-len{n}"
+         + ("" if at is None else f"-layer{at}of{STACK}")
+         for s, d, n, at in _CASES])
+def test_kernel_matches_xla_path(shape, dtype, length, layer):
     """Slot 0 holds ``length`` tokens, slot 1 is inactive (``pos ==
-    -1``), slot 2 holds 33 (a partial third page)."""
+    -1``), slot 2 holds 33 (a partial third page). With ``layer``, the
+    pools are a stack of ``STACK`` layers' pools and the step works on
+    ``[layer]`` of it: it attends exactly as it does to that layer's
+    pool alone, and its append writes the rows of the active slots into
+    that layer and changes no other byte of the stack."""
     lengths = [length, 0, 33]
     p, x, s, pool, table, pos = _layer(SHAPES[shape], dtype, lengths)
     want, want_pool = attention_decode_paged(p, x, s, pool, table, pos,
                                              backend="xla")
+    if layer is not None:
+        stack = _stack(pool, layer)
+        got, got_stack = attention_decode_paged(
+            p, x, s, stack, table, pos, backend="xla", layer=layer)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        tab, at = np.asarray(table), np.asarray(pos)
+        rows = {(layer, int(tab[b, at[b] // PAGE]), int(at[b] % PAGE))
+                for b in range(len(lengths)) if at[b] >= 0}
+        for before, after, alone in zip(jax.tree.leaves(stack),
+                                        jax.tree.leaves(got_stack),
+                                        jax.tree.leaves(want_pool)):
+            before, after = np.asarray(before), np.asarray(after)
+            changed = np.argwhere((before != after).any(axis=-1))
+            assert {tuple(int(i) for i in r) for r in changed} == rows
+            np.testing.assert_array_equal(after[layer], np.asarray(alone))
+        pool, want_pool = stack, got_stack
     got, got_pool = attention_decode_paged(p, x, s, pool, table, pos,
-                                           backend="pallas")
+                                           backend="pallas", layer=layer)
     for a, b in zip(jax.tree.leaves(got_pool), jax.tree.leaves(want_pool)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     got = np.asarray(got, np.float32)
@@ -131,11 +172,11 @@ def test_kernel_ignores_stale_vmem_and_races_nothing():
     q = jax.random.normal(jax.random.PRNGKey(3), (len(lengths), h, dh))
     params = pltpu.InterpretParams(uninitialized_memory="nan",
                                    detect_races=True)
+    k, v = pool.k[None], pool.v[None]
     got = paged_decode_attention_pallas(
-        q, pool.k, pool.v, jnp.maximum(pos + 1, 0), table,
-        interpret=params)
+        q, k, v, jnp.maximum(pos + 1, 0), table, 0, interpret=params)
     assert not interpret_pallas_call.races.races_found
     want = paged_decode_attention_pallas(
-        q, pool.k, pool.v, jnp.maximum(pos + 1, 0), table, interpret=True)
+        q, k, v, jnp.maximum(pos + 1, 0), table, 0, interpret=True)
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

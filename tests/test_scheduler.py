@@ -3,9 +3,9 @@
 Every sequence decoded through the continuous-batching scheduler (paged
 KV cache, ragged admission, slot reuse) must produce token-for-token
 identical output to the fixed-batch ``prefill`` + ``decode_step`` path
-on the same params — for both attention families (MHA KV cache and
-MLA latent cache), untuned on both sides so the comparison is pure
-cache plumbing.
+on the same params — for both attention families (MHA and GQA KV
+caches, MLA latent cache), untuned on both sides so the comparison is
+pure cache plumbing.
 """
 
 import jax
@@ -19,8 +19,9 @@ from repro.train.step import make_ctx
 
 pytestmark = pytest.mark.timeout(300)
 
-#: the two attention families with a paged cache representation
-ARCHS = ["stablelm-1.6b", "deepseek-v2-236b"]
+#: the attention families with a paged cache representation: MHA, GQA
+#: and MLA
+ARCHS = ["stablelm-1.6b", "granite-8b", "deepseek-v2-236b"]
 
 _BUILT: dict = {}
 
