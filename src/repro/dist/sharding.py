@@ -177,7 +177,7 @@ def paged_spec(shape: Sequence[int], mesh, page_dim: int = 0
                ) -> PartitionSpec:
     """Spec for a paged KV pool — 2D (data x model) on one array.
 
-    Page pools (:mod:`repro.serve.kv_cache`) carry no batch dim: the
+    Page pools (:mod:`repro.models.paged_cache`) carry no batch dim: the
     *page* dim is the parallel one, so it takes the data axes (demoted
     until they divide).  Tensor parallelism goes to the largest
     remaining dim divisible by 'model' — excluding the page-offset dim

@@ -3,7 +3,7 @@
 The continuous-batching decode step attends each slot's one new query
 token to the slot's cached keys and values. They live in a shared page
 pool ``(n_pages, page_size, n_kv_heads * head_dim)`` (one token's KV
-heads side by side in one row, :mod:`repro.serve.kv_cache`), addressed
+heads side by side in one row, :mod:`repro.models.paged_cache`), addressed
 through a per-slot page table. The kernel takes a stack of such pools,
 one per layer of the model's layer scan, and the index of the layer to
 read, so the scan hands it the stack it carries and no layer's pool is

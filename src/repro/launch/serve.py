@@ -21,8 +21,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, build_model, get_config, get_smoke_config
 from repro.launch.compile_cache import enable_compile_cache
-from repro.models.transformer import Ctx
-from repro.train.step import make_ctx
+from repro.models.transformer import Ctx, make_ctx
 
 #: total-variation distance between the serving routine mix and the
 #: installed workload profile above which serve warns (0 = identical)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ops
 from repro.kernels.paged_attention import paged_decode_attention_pallas
+from repro.models.paged_cache import append_token, gather_pages
 from repro.models.params import ParamDef
 
 __all__ = [
@@ -361,6 +362,24 @@ def _dequantize_kv(q: jax.Array, scale: jax.Array,
             * scale.astype(jnp.float32)[..., None]).astype(dtype)
 
 
+def _attend_one_token(q: jax.Array, k: jax.Array, v: jax.Array,
+                      valid: jax.Array, s: AttnSpec) -> jax.Array:
+    """The XLA decode attention core shared by the contiguous and the
+    paged cache: scores, mask, softmax and weighted sum at f32.
+
+    q (B, 1, H, Dh); k, v (B, cap, Hkv, Dh); ``valid`` (1 or B, cap)
+    marks the cache slots each sequence may attend to.  Returns
+    (B, H, Dh) f32.  One copy keeps the paged path bitwise equal to the
+    fixed-batch one (the scheduler's golden-parity contract)."""
+    kk = _repeat_kv(k, s.n_heads)
+    vv = _repeat_kv(v, s.n_heads)
+    scores = jnp.einsum("bohd,bkhd->bhk", q.astype(jnp.float32),
+                        kk.astype(jnp.float32)) * (s.head_dim ** -0.5)
+    scores = jnp.where(valid[:, None, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhk,bkhd->bhd", probs, vv.astype(jnp.float32))
+
+
 def attention_decode(p: dict, x: jax.Array, s: AttnSpec, cache: KVCache,
                      pos: jax.Array, tuner=None
                      ) -> tuple[jax.Array, KVCache]:
@@ -394,10 +413,6 @@ def attention_decode(p: dict, x: jax.Array, s: AttnSpec, cache: KVCache,
         v = jax.lax.dynamic_update_slice(cache.v, v_new, (0, slot, 0, 0))
         new_cache = KVCache(k, v, cache.windowed)
 
-    kk = _repeat_kv(k, s.n_heads)
-    vv = _repeat_kv(v, s.n_heads)
-    scores = jnp.einsum("bohd,bkhd->bhk", q.astype(jnp.float32),
-                        kk.astype(jnp.float32)) * (s.head_dim ** -0.5)
     kv_ids = jnp.arange(cap)
     if cache.windowed:
         # ring buffer: valid slots are the last min(pos+1, cap) writes
@@ -405,9 +420,7 @@ def attention_decode(p: dict, x: jax.Array, s: AttnSpec, cache: KVCache,
         valid = age < jnp.minimum(pos + 1, cap)
     else:
         valid = kv_ids <= pos
-    scores = jnp.where(valid[None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhk,bkhd->bhd", probs, vv.astype(jnp.float32))
+    out = _attend_one_token(q, k, v, valid[None], s)
     out = out.reshape(b, 1, s.n_heads * s.head_dim).astype(x.dtype)
     ops.observe(b, s.n_heads * s.head_dim, x.shape[-1], tuner,
                 site="attn.out_proj")
@@ -424,7 +437,7 @@ def attention_decode_paged(p: dict, x: jax.Array, s: AttnSpec, pool,
     sits at its own position (ragged admission), with -1 marking an
     inactive batch slot; ``page_table`` (B, P) int32 maps each
     sequence's logical pages to physical pages of ``pool``
-    (:class:`repro.serve.kv_cache.PagedKV`), -1 marking holes.  With
+    (:class:`repro.models.paged_cache.PagedKV`), -1 marking holes.  With
     ``layer`` (a scalar index), ``pool`` holds the stacked pools of
     every layer of a scan, ``(L, n_pages, page, Hkv * Dh)``: this layer
     writes its rows into ``pool[layer]`` and reads from there, and the
@@ -447,8 +460,6 @@ def attention_decode_paged(p: dict, x: jax.Array, s: AttnSpec, pool,
     sequential append + triangular-prefix read, just scattered through
     the page table.
     """
-    from repro.serve.kv_cache import append_token, gather_pages
-
     if s.window is not None:
         raise NotImplementedError(
             "paged decode does not support sliding-window (ring) caches")
@@ -474,14 +485,8 @@ def attention_decode_paged(p: dict, x: jax.Array, s: AttnSpec, pool,
         kv = (b, cap, s.n_kv_heads, s.head_dim)
         k = gather_pages(pool.k, page_table, layer).reshape(kv)
         v = gather_pages(pool.v, page_table, layer).reshape(kv)
-        kk = _repeat_kv(k, s.n_heads)
-        vv = _repeat_kv(v, s.n_heads)
-        scores = jnp.einsum("bohd,bkhd->bhk", q.astype(jnp.float32),
-                            kk.astype(jnp.float32)) * (s.head_dim ** -0.5)
-        valid = jnp.arange(cap)[None, :] <= pos[:, None]
-        scores = jnp.where(valid[:, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bhk,bkhd->bhd", probs, vv.astype(jnp.float32))
+        out = _attend_one_token(q, k, v,
+                                jnp.arange(cap)[None, :] <= pos[:, None], s)
     out = out.reshape(b, 1, s.n_heads * s.head_dim).astype(x.dtype)
     ops.observe(b, s.n_heads * s.head_dim, x.shape[-1], tuner,
                 site="attn.out_proj")

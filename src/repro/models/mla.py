@@ -22,6 +22,7 @@ from repro.models.layers import (
     rmsnorm,
     rope_angles,
 )
+from repro.models.paged_cache import append_token, gather_pages
 from repro.models.params import ParamDef
 
 __all__ = ["MLASpec", "mla_defs", "mla_train", "mla_decode",
@@ -172,6 +173,34 @@ def init_mla_cache(batch: int, capacity: int, s: MLASpec,
         jnp.zeros((batch, capacity, s.qk_rope_dim), dtype))
 
 
+def _absorbed_attend(p: dict, q: jax.Array, c_kv: jax.Array,
+                     k_rope: jax.Array, valid: jax.Array,
+                     s: MLASpec) -> jax.Array:
+    """The XLA MLA decode core shared by the contiguous and the paged
+    cache, in latent space at f32.
+
+    q (B, 1, H, nope + rope); c_kv (B, cap, R_kv); k_rope
+    (B, cap, rope); ``valid`` (1 or B, cap) marks the cache slots each
+    sequence may attend to.  Returns (B, H, v_head_dim) f32.  One copy
+    keeps the paged path bitwise equal to the fixed-batch one."""
+    q_nope = q[..., : s.qk_nope_dim]       # (B, 1, H, nope)
+    q_rope = q[..., s.qk_nope_dim:]        # (B, 1, H, rope)
+    # absorb: q_lat[h] = q_nope[h] @ wk_b[h]^T  -> (B, H, R_kv)
+    wk_b = p["wk_b"].reshape(s.kv_lora_rank, s.n_heads, s.qk_nope_dim)
+    q_lat = jnp.einsum("bohd,rhd->bhr", q_nope.astype(jnp.float32),
+                       wk_b.astype(jnp.float32))
+    s_lat = jnp.einsum("bhr,bkr->bhk", q_lat, c_kv.astype(jnp.float32))
+    s_rope = jnp.einsum("bohd,bkd->bhk", q_rope.astype(jnp.float32),
+                        k_rope.astype(jnp.float32))
+    scores = (s_lat + s_rope) * (s.qk_head_dim ** -0.5)
+    scores = jnp.where(valid[:, None, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    # output in latent space, then decompress through wv_b per head
+    o_lat = jnp.einsum("bhk,bkr->bhr", probs, c_kv.astype(jnp.float32))
+    wv_b = p["wv_b"].reshape(s.kv_lora_rank, s.n_heads, s.v_head_dim)
+    return jnp.einsum("bhr,rhd->bhd", o_lat, wv_b.astype(jnp.float32))
+
+
 def mla_decode(p: dict, x: jax.Array, s: MLASpec, cache: MLACache,
                pos: jax.Array, tuner=None) -> tuple[jax.Array, MLACache]:
     """One-token decode against the latent cache.
@@ -189,26 +218,8 @@ def mla_decode(p: dict, x: jax.Array, s: MLASpec, cache: MLACache,
     cache = MLACache(
         jax.lax.dynamic_update_slice(cache.c_kv, c_kv_new, (0, pos, 0)),
         jax.lax.dynamic_update_slice(cache.k_rope, k_rope_new, (0, pos, 0)))
-    cap = cache.c_kv.shape[1]
-    q_nope = q[..., : s.qk_nope_dim]       # (B, 1, H, nope)
-    q_rope = q[..., s.qk_nope_dim:]        # (B, 1, H, rope)
-    # absorb: q_lat[h] = q_nope[h] @ wk_b[h]^T  -> (B, H, R_kv)
-    wk_b = p["wk_b"].reshape(s.kv_lora_rank, s.n_heads, s.qk_nope_dim)
-    q_lat = jnp.einsum("bohd,rhd->bhr", q_nope.astype(jnp.float32),
-                       wk_b.astype(jnp.float32))
-    s_lat = jnp.einsum("bhr,bkr->bhk", q_lat,
-                       cache.c_kv.astype(jnp.float32))
-    s_rope = jnp.einsum("bohd,bkd->bhk", q_rope.astype(jnp.float32),
-                        cache.k_rope.astype(jnp.float32))
-    scores = (s_lat + s_rope) * (s.qk_head_dim ** -0.5)
-    valid = jnp.arange(cap) <= pos
-    scores = jnp.where(valid[None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    # output in latent space, then decompress through wv_b per head
-    o_lat = jnp.einsum("bhk,bkr->bhr", probs,
-                       cache.c_kv.astype(jnp.float32))
-    wv_b = p["wv_b"].reshape(s.kv_lora_rank, s.n_heads, s.v_head_dim)
-    out = jnp.einsum("bhr,rhd->bhd", o_lat, wv_b.astype(jnp.float32))
+    valid = jnp.arange(cache.c_kv.shape[1]) <= pos
+    out = _absorbed_attend(p, q, cache.c_kv, cache.k_rope, valid[None], s)
     out = out.reshape(b, 1, s.n_heads * s.v_head_dim).astype(x.dtype)
     return linear(out, p["wo"]), cache
 
@@ -221,15 +232,13 @@ def mla_decode_paged(p: dict, x: jax.Array, s: MLASpec, pool,
     The paged twin of :func:`mla_decode`: ``pos`` is (B,) int32 per-
     sequence positions (-1 = inactive slot), ``page_table`` (B, P)
     maps logical pages to physical pages of the
-    :class:`repro.serve.kv_cache.PagedLatent` pool; with ``layer``,
+    :class:`repro.models.paged_cache.PagedLatent` pool; with ``layer``,
     ``pool`` stacks every scanned layer's pools and this layer reads and
     writes ``pool[layer]``, returning the whole stack.  Same absorbed-
     projection math on the page-gathered latent view, bitwise equal
     per sequence to the contiguous path; the latent cache update keeps
     its TRSM-site recorder tag.
     """
-    from repro.serve.kv_cache import append_token, gather_pages
-
     b = x.shape[0]
     q, c_kv_new, k_rope_new = _latents(p, x, s, pos[:, None], tuner)
     cap = page_table.shape[1] * pool.page_size
@@ -243,20 +252,7 @@ def mla_decode_paged(p: dict, x: jax.Array, s: MLASpec, pool,
                      active, layer))
     c_kv = gather_pages(pool.c_kv, page_table, layer)      # (B, cap, R_kv)
     k_rope = gather_pages(pool.k_rope, page_table, layer)  # (B, cap, R_rope)
-    q_nope = q[..., : s.qk_nope_dim]       # (B, 1, H, nope)
-    q_rope = q[..., s.qk_nope_dim:]        # (B, 1, H, rope)
-    wk_b = p["wk_b"].reshape(s.kv_lora_rank, s.n_heads, s.qk_nope_dim)
-    q_lat = jnp.einsum("bohd,rhd->bhr", q_nope.astype(jnp.float32),
-                       wk_b.astype(jnp.float32))
-    s_lat = jnp.einsum("bhr,bkr->bhk", q_lat, c_kv.astype(jnp.float32))
-    s_rope = jnp.einsum("bohd,bkd->bhk", q_rope.astype(jnp.float32),
-                        k_rope.astype(jnp.float32))
-    scores = (s_lat + s_rope) * (s.qk_head_dim ** -0.5)
-    valid = jnp.arange(cap)[None, :] <= pos[:, None]
-    scores = jnp.where(valid[:, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    o_lat = jnp.einsum("bhk,bkr->bhr", probs, c_kv.astype(jnp.float32))
-    wv_b = p["wv_b"].reshape(s.kv_lora_rank, s.n_heads, s.v_head_dim)
-    out = jnp.einsum("bhr,rhd->bhd", o_lat, wv_b.astype(jnp.float32))
+    out = _absorbed_attend(p, q, c_kv, k_rope,
+                           jnp.arange(cap)[None, :] <= pos[:, None], s)
     out = out.reshape(b, 1, s.n_heads * s.v_head_dim).astype(x.dtype)
     return linear(out, p["wo"]), pool

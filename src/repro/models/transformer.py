@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from typing import Any
 
 import jax
@@ -25,12 +24,13 @@ import jax.numpy as jnp
 from repro.models import layers as L
 from repro.models import mla as MLA
 from repro.models import moe as MOE
+from repro.models import paged_cache as PC
 from repro.models import recurrent as REC
 from repro.models import xlstm as XL
 from repro.models.config import ArchConfig, ShapeSpec
 from repro.models.params import ParamDef, init_params, param_specs
 
-__all__ = ["LM", "build_lm", "chunked_cross_entropy"]
+__all__ = ["LM", "Ctx", "make_ctx", "build_lm", "chunked_cross_entropy"]
 
 
 # ---------------------------------------------------------------------------
@@ -179,6 +179,19 @@ class Ctx:
         return "auto"
 
 
+def make_ctx(mesh, mode: str, *, cache_len: int = 0,
+             remat: bool = True, tuner=None) -> Ctx:
+    """The :class:`Ctx` of a step in ``mode`` (train | prefill |
+    decode): unmeshed when ``mesh`` is None, else data-parallel over
+    every mesh axis but ``"model"``, which takes tensor parallelism."""
+    if mesh is None:
+        return Ctx(mode=mode, cache_len=cache_len, remat=remat,
+                   tuner=tuner)
+    dp = tuple(a for a in mesh.axis_names if a != "model")
+    return Ctx(mode=mode, mesh=mesh, dp_axes=dp, tp_axis="model",
+               cache_len=cache_len, remat=remat, tuner=tuner)
+
+
 def _moe_apply(p: dict, x: jax.Array, cfg: ArchConfig, ctx: Ctx
                ) -> tuple[jax.Array, jax.Array]:
     """MoE dispatch-path selection.
@@ -314,8 +327,6 @@ def _init_layer_paged(cfg: ArchConfig, spec: LayerSpec, n_pages: int,
     have no paged representation (the window bounds their memory
     already; recurrent states carry no sequence dim) — continuous
     batching supports the attention-cache families."""
-    from repro.serve import kv_cache as KV
-
     if spec.kind not in ("attn", "local"):
         raise NotImplementedError(
             f"paged decode cache for layer kind {spec.kind!r} "
@@ -324,14 +335,41 @@ def _init_layer_paged(cfg: ArchConfig, spec: LayerSpec, n_pages: int,
         raise NotImplementedError("paged decode with int8 KV cache")
     if cfg.attn_kind == "mla":
         s = _mla_spec(cfg)
-        return KV.init_paged_latent(n_pages, page_size, s.kv_lora_rank,
+        return PC.init_paged_latent(n_pages, page_size, s.kv_lora_rank,
                                     s.qk_rope_dim, dtype)
     a = _attn_spec(cfg, spec.kind)
     if a.window is not None:
         raise NotImplementedError(
             "paged decode cache for sliding-window (ring) layers")
-    return KV.init_paged_kv(n_pages, page_size, a.n_kv_heads,
+    return PC.init_paged_kv(n_pages, page_size, a.n_kv_heads,
                             a.head_dim, dtype)
+
+
+def _seed_segment(pool_seg: Any, cache_seg: Any, page_ids: jax.Array,
+                  stacked: bool) -> Any:
+    """Write a prefill cache segment into the matching pool segment.
+
+    The two trees differ in node type (``KVCache`` vs ``PagedKV``,
+    ``MLACache`` vs ``PagedLatent``) but align leaf-for-leaf — k with
+    k, v with v, c_kv with c_kv — so the zip below is the whole
+    mapping.  ``stacked`` handles the scan segment's extra leading
+    repeat dim ((R, 1, cap, ...) rows into (R, P, page, ...) pools).
+    """
+    leaves, treedef = jax.tree.flatten(pool_seg)
+    vals = jax.tree.leaves(cache_seg)
+    if len(leaves) != len(vals):
+        raise ValueError(
+            f"pool/prefill cache leaf mismatch ({len(leaves)} vs "
+            f"{len(vals)}) — unsupported cache variant for paging")
+    out = []
+    for pl, vl in zip(leaves, vals):
+        if stacked:
+            out.append(jax.vmap(
+                lambda pool, rows: PC.seed_pages(pool, page_ids, rows)
+            )(pl, vl[:, 0]))
+        else:
+            out.append(PC.seed_pages(pl, page_ids, vl[0]))
+    return jax.tree.unflatten(treedef, out)
 
 
 def _apply_layer_decode(p: dict, x: jax.Array, cache: Any,
@@ -494,23 +532,9 @@ class LM:
                 return (h, aux_in + aux_here), ys
 
             if ctx.remat:
-                # ADSALA_REMAT_POLICY=dots saves matmul outputs so the
-                # backward pass recomputes only elementwise ops (§Perf:
-                # trades activation memory for ~fwd-worth of FLOPs).
-                if os.environ.get("ADSALA_REMAT_POLICY") == "dots":
-                    body = jax.checkpoint(
-                        body, policy=jax.checkpoint_policies
-                        .dots_with_no_batch_dims_saveable)
-                else:
-                    body = jax.checkpoint(body)
-            # ADSALA_SCAN_UNROLL=full unrolls the layer loop so XLA cost
-            # analysis counts every layer (dry-run accounting mode; the
-            # default scan keeps HLO small for fast compiles).
-            unroll = (self.repeats
-                      if os.environ.get("ADSALA_SCAN_UNROLL") == "full"
-                      else 1)
+                body = jax.checkpoint(body)
             (x, aux_total), scan_caches = jax.lax.scan(
-                body, (x, aux_total), params["scan"], unroll=unroll)
+                body, (x, aux_total), params["scan"])
             caches["scan"] = scan_caches if want_cache else []
 
         for p, s in zip(params["suffix"], self.suffix):
@@ -578,6 +602,18 @@ class LM:
         else:
             cache["scan"] = []
         return cache
+
+    def seed_paged_cache(self, pool: dict, cache: dict,
+                         page_ids: jax.Array) -> dict:
+        """Write one sequence's prefill cache (the second result of
+        :meth:`prefill`, batch of one, ``cache_len`` a whole number of
+        pages) into the pages ``page_ids`` of a pool tree from
+        :meth:`init_paged_cache`, every segment; returns the new pool
+        tree.  Without repeats the scan segment is empty on both sides
+        and stays so."""
+        return {seg: _seed_segment(pool[seg], cache[seg], page_ids,
+                                   stacked=seg == "scan")
+                for seg in ("prefix", "scan", "suffix")}
 
     def prefill(self, params: dict, tokens: jax.Array, ctx: Ctx
                 ) -> tuple[jax.Array, dict]:

@@ -2,7 +2,7 @@
 paged-KV continuous-batching scheduler, and the drift-triggered online
 re-install loop.
 
-``repro.serve.step`` / ``kv_cache`` / ``scheduler`` pull in jax; the
+``repro.serve.step`` / ``scheduler`` pull in jax; the
 re-install manager below is jax-free on purpose (it runs against the
 simulated/measured timing backends), so it is safe to re-export
 eagerly — the jax-backed names resolve lazily.
@@ -16,15 +16,13 @@ from repro.serve.reinstall import (
 
 __all__ = ["DriftTrigger", "ReinstallConfig", "ReinstallManager",
            "ContinuousBatchingScheduler", "Request", "FinishedSeq",
-           "PageAllocator", "PagedKV", "PagedLatent"]
+           "PageAllocator"]
 
 _LAZY = {
     "ContinuousBatchingScheduler": "repro.serve.scheduler",
     "Request": "repro.serve.scheduler",
     "FinishedSeq": "repro.serve.scheduler",
     "PageAllocator": "repro.serve.kv_cache",
-    "PagedKV": "repro.serve.kv_cache",
-    "PagedLatent": "repro.serve.kv_cache",
 }
 
 

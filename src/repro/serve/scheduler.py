@@ -4,7 +4,8 @@ Fixed-batch serving admits one batch and steps it until the *slowest*
 sequence finishes: every early-finishing slot idles, so goodput on
 ragged-length traffic collapses toward the longest request.  The
 scheduler here keeps a fixed number of decode **slots** and a shared
-page pool (:mod:`repro.serve.kv_cache`); per step it
+page pool (:mod:`repro.models.paged_cache`, pages handed out by
+:class:`~repro.serve.kv_cache.PageAllocator`); per step it
 
 1. admits queued requests into free slots — the prompt is prefilled at
    its exact length and its cache rows are seeded into freshly
@@ -57,19 +58,16 @@ import dataclasses
 import threading
 import time
 from collections import deque
-from typing import Any, Callable
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from repro.serve.kv_cache import (
-    HOLE,
-    PageAllocator,
-    pages_for,
-    seed_pages,
-)
+from repro.models.paged_cache import HOLE
+from repro.models.transformer import make_ctx
+from repro.serve.kv_cache import PageAllocator, pages_for
 
 __all__ = ["Request", "FinishedSeq", "ContinuousBatchingScheduler"]
 
@@ -101,33 +99,6 @@ class FinishedSeq:
     submitted_at: float
     admitted_at: float
     token_times: tuple[float, ...]
-
-
-def _seed_segment(pool_seg: Any, cache_seg: Any, page_ids: jax.Array,
-                  stacked: bool) -> Any:
-    """Write a prefill cache segment into the matching pool segment.
-
-    The two trees differ in node type (``KVCache`` vs ``PagedKV``,
-    ``MLACache`` vs ``PagedLatent``) but align leaf-for-leaf — k with
-    k, v with v, c_kv with c_kv — so the zip below is the whole
-    mapping.  ``stacked`` handles the scan segment's extra leading
-    repeat dim ((R, 1, cap, ...) rows into (R, P, page, ...) pools).
-    """
-    leaves, treedef = jax.tree.flatten(pool_seg)
-    vals = jax.tree.leaves(cache_seg)
-    if len(leaves) != len(vals):
-        raise ValueError(
-            f"pool/prefill cache leaf mismatch ({len(leaves)} vs "
-            f"{len(vals)}) — unsupported cache variant for paging")
-    out = []
-    for pl, vl in zip(leaves, vals):
-        if stacked:
-            out.append(jax.vmap(
-                lambda pool, rows: seed_pages(pool, page_ids, rows)
-            )(pl, vl[:, 0]))
-        else:
-            out.append(seed_pages(pl, page_ids, vl[0]))
-    return jax.tree.unflatten(treedef, out)
 
 
 class ContinuousBatchingScheduler:
@@ -162,7 +133,6 @@ class ContinuousBatchingScheduler:
         if slots < 1:
             raise ValueError(f"slots={slots} < 1")
         from repro.kernels.recorder import DispatchRecorder
-        from repro.train.step import make_ctx
 
         self.model, self.cfg, self.params = model, cfg, params
         self.slots = int(slots)
@@ -178,7 +148,6 @@ class ContinuousBatchingScheduler:
         self.alloc = PageAllocator(n_pages, page_size)
 
         self._dtype = dtype
-        self._make_ctx = make_ctx
         self._dctx = make_ctx(None, "decode", cache_len=self.cap,
                               tuner=tuner)
         self.pool = model.init_paged_cache(n_pages, page_size,
@@ -259,8 +228,8 @@ class ContinuousBatchingScheduler:
             # logits_last sees the real last token, not padding
             cache_len = pages_for(prompt_len, self.page_size) \
                 * self.page_size
-            pctx = self._make_ctx(None, "prefill", cache_len=cache_len,
-                                  remat=False, tuner=self.tuner)
+            pctx = make_ctx(None, "prefill", cache_len=cache_len,
+                            remat=False, tuner=self.tuner)
             fn = jax.jit(
                 lambda p, toks: self.model.prefill(p, toks, pctx))
             self._prefills[prompt_len] = fn
@@ -300,16 +269,8 @@ class ContinuousBatchingScheduler:
                                  pages=n_prompt_pages):
                 page_ids = jnp.asarray(
                     np.asarray(pages[:n_prompt_pages], np.int32))
-                pool = self.pool
-                self.pool = {
-                    "prefix": _seed_segment(pool["prefix"], cache["prefix"],
-                                            page_ids, stacked=False),
-                    "scan": (_seed_segment(pool["scan"], cache["scan"],
-                                           page_ids, stacked=True)
-                             if self.model.repeats else pool["scan"]),
-                    "suffix": _seed_segment(pool["suffix"], cache["suffix"],
-                                            page_ids, stacked=False),
-                }
+                self.pool = self.model.seed_paged_cache(self.pool, cache,
+                                                        page_ids)
             first = int(jnp.argmax(logits[0]))
         self._times[slot] = [time.perf_counter()]
         row = np.full((self.table_pages,), HOLE, np.int32)

@@ -23,7 +23,8 @@ from repro.dist.sharding import (
     partition_params,
 )
 from repro.models.config import ArchConfig, ShapeSpec
-from repro.train.step import make_ctx
+from repro.models.paged_cache import PagedKV, PagedLatent
+from repro.models.transformer import make_ctx
 
 __all__ = ["build_prefill", "build_decode", "build_decode_paged",
            "prefill_batch_sds", "decode_inputs_sds", "cache_specs",
@@ -57,7 +58,6 @@ def paged_cache_sds(model, n_pages: int, page_size: int,
 
 
 def _is_pool(x: Any) -> bool:
-    from repro.serve.kv_cache import PagedKV, PagedLatent
     return isinstance(x, (PagedKV, PagedLatent))
 
 

@@ -8,7 +8,6 @@ ShapeDtypeStructs without allocating anything.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Any
 
 import jax
@@ -18,7 +17,7 @@ from jax.sharding import PartitionSpec as P
 from repro.dist.sharding import batch_specs, partition_params, state_specs
 from repro.kernels import recorder
 from repro.models.config import ArchConfig, ShapeSpec
-from repro.models.transformer import Ctx
+from repro.models.transformer import make_ctx
 from repro.train.optim import (
     STATE_MOMENTS,
     AdamWConfig,
@@ -28,20 +27,6 @@ from repro.train.optim import (
 
 __all__ = ["build_train_step", "make_ctx", "abstract_state",
            "train_batch_sds"]
-
-
-def make_ctx(mesh, mode: str, *, cache_len: int = 0,
-             remat: bool = True, tuner=None) -> Ctx:
-    # §Perf knob: ADSALA_KV_INT8=1 switches serving caches to int8
-    kv_q = (os.environ.get("ADSALA_KV_INT8") == "1"
-            and mode in ("prefill", "decode"))
-    if mesh is None:
-        return Ctx(mode=mode, cache_len=cache_len, remat=remat,
-                   kv_quantized=kv_q, tuner=tuner)
-    dp = tuple(a for a in mesh.axis_names if a != "model")
-    return Ctx(mode=mode, mesh=mesh, dp_axes=dp, tp_axis="model",
-               cache_len=cache_len, remat=remat, kv_quantized=kv_q,
-               tuner=tuner)
 
 
 def abstract_state(model, cfg: ArchConfig, opt_cfg: AdamWConfig,

@@ -16,7 +16,8 @@ import pytest
 
 from repro.configs import build_model, get_smoke_config
 from repro.models.layers import AttnSpec, attention_decode_paged
-from repro.serve.kv_cache import HOLE, PagedKV, pages_for
+from repro.models.paged_cache import HOLE, PagedKV
+from repro.serve.kv_cache import pages_for
 from repro.serve.scheduler import ContinuousBatchingScheduler
 
 pytestmark = pytest.mark.timeout(300)

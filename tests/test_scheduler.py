@@ -14,6 +14,8 @@ import numpy as np
 import pytest
 
 from repro.configs import build_model, get_smoke_config
+from repro.models.paged_cache import gather_pages
+from repro.serve.kv_cache import pages_for
 from repro.serve.scheduler import ContinuousBatchingScheduler
 from repro.train.step import make_ctx
 
@@ -103,6 +105,51 @@ def test_slot_reuse_does_not_cross_contaminate(arch):
     finished = sched.run_until_drained()
     assert finished[first].tokens == finished[again].tokens
     assert len(finished) == len(fillers) + 2
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_seed_paged_cache_holds_the_prefill_rows(arch):
+    """``LM.seed_paged_cache`` writes one prompt's prefill cache into
+    the sequence's pages: gathering them through its page table gives
+    back the contiguous cache's rows for every leaf and layer, prefix
+    and scan segments alike, and no other page is written."""
+    cfg, model, params = _built(arch)
+    page, n_pages = 4, 16
+    prompt = np.random.default_rng(5).integers(0, cfg.vocab, 7)
+    cache_len = pages_for(len(prompt), page) * page
+    pctx = make_ctx(None, "prefill", cache_len=cache_len, remat=False)
+    _, cache = model.prefill(
+        params, jnp.asarray(prompt[None], jnp.int32), pctx)
+    pool = model.init_paged_cache(n_pages, page,
+                                  make_ctx(None, "decode"), jnp.float32)
+    pages = [11, 3]                       # out of order, not from 0
+    pool = model.seed_paged_cache(pool, cache,
+                                  jnp.asarray(pages, jnp.int32))
+    table = jnp.asarray([pages], jnp.int32)
+    others = np.setdiff1d(np.arange(n_pages), pages)
+
+    checked = {}
+    for seg in ("prefix", "scan", "suffix"):
+        pools = jax.tree.leaves(pool[seg])
+        rows = jax.tree.leaves(cache[seg])
+        assert len(pools) == len(rows), seg
+        for pl, rl in zip(pools, rows):
+            if seg == "scan":
+                assert pl.shape[0] == rl.shape[0] == model.repeats
+                for layer in range(model.repeats):
+                    got = gather_pages(pl, table, jnp.int32(layer))
+                    np.testing.assert_array_equal(
+                        got[0], rl[layer, 0].reshape(cache_len, -1))
+                assert not np.asarray(pl)[:, others].any(), seg
+            else:
+                got = gather_pages(pl, table)
+                np.testing.assert_array_equal(
+                    got[0], rl[0].reshape(cache_len, -1))
+                assert not np.asarray(pl)[others].any(), seg
+        checked[seg] = len(pools)
+    assert checked["scan"] > 0
+    assert (checked["prefix"] > 0) == bool(model.prefix)
+    assert (checked["suffix"] > 0) == bool(model.suffix)
 
 
 def test_trsm_site_tags_survive_paging():
